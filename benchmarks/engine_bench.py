@@ -148,6 +148,8 @@ from benchmarks.common import timeit
 from repro.core import (RoundRobinSequencer, destm_execute, fingerprint,
                         legacy_scan, make_store, occ_execute, pcc_execute)
 from repro.core import workloads as W
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 
 def _workload(k: int, contention: str, seed: int = 0, *,
@@ -936,7 +938,7 @@ def run_shard_smoke() -> None:
     n_dev = len(jax.devices())
     if n_dev >= 2:
         s = min(8, n_dev)
-        mesh = jax.make_mesh((s,), ("shard",), devices=jax.devices()[:s])
+        mesh = make_host_mesh(s, "shard")
         wl = _workload(32, "med", seed=19)
         seq = _seq_for(wl)
         dense = make_store(wl.n_objects)
@@ -1243,6 +1245,7 @@ def run() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny K, equivalence assertions only (CI stage)")

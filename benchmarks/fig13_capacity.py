@@ -18,6 +18,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 
 VMEM = 16 * 2**20
 
@@ -65,4 +66,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -20,6 +20,7 @@ from repro.models import lm
 from repro.runtime.shardings import SMOKE
 from repro.train import make_train_step
 from repro.train.train_step import init_state
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run() -> None:
@@ -51,4 +52,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

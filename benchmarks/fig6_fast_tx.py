@@ -18,6 +18,7 @@ from benchmarks.common import emit, timeit
 from repro.core import (RMW, READ, WRITE, make_batch, make_store,
                         pcc_execute)
 from repro.core.metrics import _txn_cost
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run() -> None:
@@ -54,4 +55,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -14,6 +14,7 @@ import numpy as np
 from benchmarks.common import emit, run_engines
 from repro.core import workloads as W
 from repro.core import metrics as M
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def pot_variants(wl):
@@ -75,4 +76,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

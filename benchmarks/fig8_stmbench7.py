@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from benchmarks.common import emit, run_engines
 from repro.core import workloads as W
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run() -> None:
@@ -22,4 +23,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -14,6 +14,8 @@ import json
 import os
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 VARIANTS = {
     "base": {},
     "pure_dp": {"profile_patch": {"pure_dp": True}},
@@ -69,6 +71,7 @@ def run_variant(arch: str, shape: str, variant: str, out_dir: str):
 
 
 def main():
+    enable_compile_cache()
     arch, shape, variant = sys.argv[1], sys.argv[2], sys.argv[3]
     rec = run_variant(arch, shape, variant, "results/perf")
     ex = rec["analysis"]["extrapolated"]

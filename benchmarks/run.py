@@ -17,6 +17,8 @@ def main() -> None:
                     help="substring filter on benchmark module names")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (engine_bench, fig6_fast_tx, fig7_stamp,
                             fig8_stmbench7, fig9_wait, fig11_scalability,
                             fig13_capacity, fig14_det_training, roofline)

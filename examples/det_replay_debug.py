@@ -14,6 +14,9 @@ Run:  PYTHONPATH=src python examples/det_replay_debug.py
 import numpy as np
 
 from repro.core import (READ, WRITE, PotSession, ReplaySequencer, make_batch)
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # object 0: the resource (0 = uninitialized); object 1: consumer's result
 INIT = [(WRITE, 0, False, 42)]          # thread 1: initialize

@@ -12,7 +12,7 @@ log, the SAME formed batches as a replica that never crashed.
 
 Three acts:
 
-1. **Replica A** serves the whole journal uninterrupted (in-process),
+1. **Replica A** serves the whole journal uninterrupted (a subprocess),
    snapshotting after every 2nd formed batch.
 2. **Replica B** runs as a real subprocess (``python -m
    repro.core.checkpoint``) with a deterministic :class:`FaultPlan`:
@@ -23,6 +23,10 @@ Three acts:
    fingerprint, replay log, per-batch trace digests — is asserted
    bit-identical to A's.
 
+Every replica is its own process and takes the accelerator in turn; this
+parent only builds the arrival journal, on the host CPU, so it never
+holds the device a replica needs.
+
 Run:  PYTHONPATH=src python examples/failover.py
 """
 
@@ -32,10 +36,14 @@ import subprocess
 import sys
 import tempfile
 
-from repro.core import IngressPool, run_replica, trace_digest
-from repro.core import workloads as W
-from repro.core.checkpoint import snapshot_ids
-from repro.core.ingress import programs_from_batch
+import jax
+
+jax.config.update("jax_platforms", "cpu")   # before anything touches JAX
+
+from repro.core import IngressPool  # noqa: E402
+from repro.core import workloads as W  # noqa: E402
+from repro.core.checkpoint import snapshot_ids  # noqa: E402
+from repro.core.ingress import programs_from_batch  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_OBJECTS, N_LANES = 64, 6
@@ -54,18 +62,9 @@ kw = dict(n_objects=N_OBJECTS, engine="pcc", n_lanes=N_LANES,
 workdir = tempfile.mkdtemp(prefix="pot_failover_")
 print(f"workdir: {workdir}")
 
-# -- act 1: replica A, uninterrupted -------------------------------------
-a = run_replica(journal, directory=os.path.join(workdir, "a"), **kw)
-a_digests = [trace_digest(t) for t in a.session.traces]
-print(f"\nreplica A (uninterrupted): {a.session.batches_formed} batches, "
-      f"{a.session.snapshots_taken} snapshots, "
-      f"fingerprint 0x{a.session.fingerprint():08x}")
-
-# -- act 2: replica B takes a SIGKILL at (batch 4, phase execute) --------
-bdir = os.path.join(workdir, "b")
+# the replicas share the checkout's compile cache (or the one
+# JAX_COMPILATION_CACHE_DIR names); cache every step, however quick
 env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
-env.setdefault("JAX_COMPILATION_CACHE_DIR",
-               os.path.join(tempfile.gettempdir(), "repro_jax_pcache"))
 env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 
@@ -80,6 +79,17 @@ def drive(cfg, tag):
     return r, out_path
 
 
+# -- act 1: replica A, uninterrupted -------------------------------------
+r, out_path = drive(dict(kw, journal=journal,
+                         directory=os.path.join(workdir, "a")), "a")
+assert r.returncode == 0, r.stderr[-2000:]
+a = json.loads(open(out_path).read())
+print(f"\nreplica A (uninterrupted): {a['batches_formed']} batches, "
+      f"{a['snapshots_taken']} snapshots, "
+      f"fingerprint 0x{a['fingerprint'] & 0xffffffff:08x}")
+
+# -- act 2: replica B takes a SIGKILL at (batch 4, phase execute) --------
+bdir = os.path.join(workdir, "b")
 victim = dict(kw, journal=journal, directory=bdir,
               fault={"kill_batch": 4, "kill_phase": "execute"})
 r, out_path = drive(victim, "victim")
@@ -96,10 +106,10 @@ print(f"replica B restarted: restored from snapshot {out['restored_from']}, "
       f"replayed {out['recovery_batches']} batches from the journal suffix, "
       f"fingerprint 0x{out['fingerprint'] & 0xffffffff:08x}")
 
-assert out["fingerprint"] == a.session.fingerprint()
-assert out["replay_log"] == a.session.replay_log()
+assert out["fingerprint"] == a["fingerprint"]
+assert out["replay_log"] == a["replay_log"]
 assert out["trace_digests"] == \
-    a_digests[len(a_digests) - len(out["trace_digests"]):]
+    a["trace_digests"][len(a["trace_digests"]) - len(out["trace_digests"]):]
 assert out["pool_depth"] == 0
 print("\nrecovery ≡ uninterrupted: fingerprint, commit log and per-batch "
       "trace digests all bitwise identical — determinism IS the "

@@ -22,6 +22,9 @@ import numpy as np
 
 from repro.core import (READ, RMW, WRITE, PotSession, ReplaySequencer,
                         make_batch)
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 8 accounts, each starting with 100 units
 INIT_BALANCES = np.full(8, 100)

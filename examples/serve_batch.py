@@ -37,8 +37,10 @@ from repro.configs import get_smoke_config
 from repro.core import PotSession
 from repro.core import workloads as W
 from repro.models import lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.session import Session
 
+enable_compile_cache()
 cfg = get_smoke_config("stablelm_12b")
 params = lm.init_params(jax.random.PRNGKey(42), cfg)
 requests = [(0, 7), (1, 23), (2, 5), (3, 99)]   # (slot, first token)

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.ckpt import checkpoint as ck
 from repro.data.pipeline import DataConfig, batch_at
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.models.config import ModelConfig
@@ -50,6 +51,7 @@ def build_config(scale: str) -> ModelConfig:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--scale", choices=["25m", "100m"], default="25m")

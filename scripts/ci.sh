@@ -55,9 +55,10 @@
 # shards {1, 8} x pipeline_depth {0, 2} x two drain-budget schedules,
 # each restored from its latest complete snapshot + the arrival-journal
 # suffix and required to reconverge bitwise with the uninterrupted
-# replica (the crash-consistent failover gate).  A persistent XLA
-# compile cache is shared with the victim/recovery subprocesses so the
-# matrix is not compile-bound.
+# replica (the crash-consistent failover gate).  The victim/recovery
+# subprocesses share the persistent XLA compile cache
+# (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache/) so the matrix
+# is not compile-bound.
 #
 # Stages do NOT short-circuit each other: every requested stage runs and
 # the script exits non-zero if ANY stage failed (the last failing stage's
@@ -139,7 +140,6 @@ fi
 if [[ "$FAILOVER_SMOKE" == "1" ]]; then
   run_stage failover-smoke env \
     REPRO_FAILOVER_FULL=1 \
-    JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-${TMPDIR:-/tmp}/repro_jax_pcache}" \
     JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0 \
     python -m pytest -x -q tests/test_failover.py
 fi

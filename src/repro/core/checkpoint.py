@@ -674,6 +674,8 @@ def _main(argv) -> int:     # pragma: no cover - exercised via subprocess
     :class:`FaultPlan` field dict).  A victim run simply never writes
     its out file — SIGKILL is the point.
     """
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg_path, out_path = argv
     with open(cfg_path) as f:
         cfg = json.load(f)

@@ -66,8 +66,8 @@ rows are never addressed), the conflict analysis decomposes per shard
 into the carried K×K ``conflict`` (kernels/ops.py ``*_sharded`` twins
 of the full, masked-delta and compact-strip paths) — and
 :func:`fused_write_back` splits into S *independent* scatters (one per
-device under ``jax.experimental.shard_map`` when the layout carries a
-mesh, a vmap over the shard axis otherwise).  The invariant making S a
+device under ``jax.shard_map`` when the layout carries a mesh, a vmap
+over the shard axis otherwise).  The invariant making S a
 pure layout knob: conflict(t, u) == OR over shards of per-shard
 conflicts, and every commit decision stays in global rank space — so
 sharded runs are bit-identical to dense ones (tests/
@@ -821,8 +821,8 @@ def fused_write_back(values, versions, waddrs, wvals, wn, committing,
     and an address lives in exactly one shard, so each shard's winners
     are decided from exactly the writes the dense scatter would route
     there — bit-identical).  With ``layout.mesh`` set, the S scatters
-    run one-per-device under ``jax.experimental.shard_map``; otherwise
-    they run as one vmap over the shard axis.
+    run one-per-device under ``jax.shard_map``; otherwise they run as
+    one vmap over the shard axis.
     """
     if layout is not None and layout.sharded:
         return _fused_write_back_sharded(
@@ -880,8 +880,7 @@ def _fused_write_back_sharded(values, versions, waddrs, wvals, wn,
                 values, versions, jnp.arange(layout.shards), waddrs,
                 wvals, wn, committing, rank, seq_nos)
 
-    from jax.experimental.shard_map import shard_map
-    axis = tuple(layout.mesh.shape.keys())[0]
+    axis = layout.mesh.axis_names[0]
     spec = jax.sharding.PartitionSpec
 
     def body(values_b, versions_b, waddrs, wvals, wn, committing, rank,
@@ -890,10 +889,9 @@ def _fused_write_back_sharded(values, versions, waddrs, wvals, wn,
                     waddrs, wvals, wn, committing, rank, seq_nos)
         return v[None], ver[None]
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=layout.mesh,
         in_specs=(spec(axis), spec(axis)) + (spec(),) * 6,
         out_specs=(spec(axis), spec(axis)),
-        check_rep=False,
     )(values, versions, waddrs, wvals, wn, committing, rank,
       jnp.asarray(seq_nos, jnp.int32))

@@ -182,10 +182,11 @@ class PotSession:
         space — fingerprints, traces and ``replay_log()`` are
         bit-identical to ``shards=1`` (the dense store).
       mesh: optional 1-axis ``jax.sharding.Mesh`` of exactly ``shards``
-        devices; when given, the per-shard write-back scatters run
-        one-per-device under ``jax.experimental.shard_map``.  The mesh
-        travels on the store pytree as a static field, so it threads
-        through the cached jitted step with no signature change.
+        devices (``repro.launch.mesh.make_host_mesh(S, "shard")``); when
+        given, each device holds one shard and runs that shard's
+        write-back scatter and conflict kernels under ``jax.shard_map``.
+        The mesh travels on the store pytree as a static field, so it
+        threads through the cached jitted step with no signature change.
       pipeline_depth: speculate up to D batches ahead of the committed
         store in ``run_stream`` / ``serve`` (cross-batch pipelining —
         see the module docstring).  Bit-identical to the serial stream

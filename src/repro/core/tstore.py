@@ -54,11 +54,11 @@ class StoreLayout:
     address ``a`` maps to ``(a // shard_size, a % shard_size)``.  The
     dense store is the ``shards == 1`` case.  ``mesh`` optionally names
     a 1-axis :class:`jax.sharding.Mesh` of exactly ``shards`` devices —
-    when present, the per-shard write-back scatters run under
-    ``jax.experimental.shard_map`` over it (one device per shard);
-    when absent they run as one vmapped scatter per shard on a single
-    device.  Hashable (a static jit constant): it travels on the store
-    pytree as a meta field, so the engine step specializes per layout.
+    when present, the stacked shards live one per device and the
+    per-shard write-back scatters and conflict kernels run under
+    ``jax.shard_map`` over it; when absent they run on a single device.
+    Hashable (a static jit constant): it travels on the store pytree as
+    a meta field, so the engine step specializes per layout.
     """
 
     n_objects: int
@@ -219,19 +219,31 @@ def shard_store(store: TStore, shards: int, mesh=None):
     if shards == 1 and mesh is None:
         return store
     layout = StoreLayout(store.n_objects, shards, mesh)
+    pad = layout.padded_objects - store.n_objects
+    values = jnp.pad(store.values, ((0, pad), (0, 0))).reshape(
+        shards, layout.shard_size, store.slot)
+    versions = jnp.pad(store.versions, (0, pad)).reshape(
+        shards, layout.shard_size)
+    gv = store.gv
     if mesh is not None:
         sizes = tuple(mesh.shape.values())
         if len(sizes) != 1 or sizes[0] != shards:
             raise ValueError(
                 f"mesh must have exactly one axis of size shards={shards}, "
                 f"got axes {dict(mesh.shape)}")
-    pad = layout.padded_objects - store.n_objects
-    values = jnp.pad(store.values, ((0, pad), (0, 0)))
-    versions = jnp.pad(store.versions, (0, pad))
-    return ShardedStore(
-        values=values.reshape(shards, layout.shard_size, store.slot),
-        versions=versions.reshape(shards, layout.shard_size),
-        gv=store.gv, n_objects=store.n_objects, mesh=mesh)
+        if any(t != jax.sharding.AxisType.Auto for t in mesh.axis_types):
+            raise ValueError(
+                f"the store mesh needs an Auto axis, got {mesh.axis_types}: "
+                f"build it with repro.launch.mesh.make_host_mesh")
+        # one shard per device: shard s's (C, slot) image on device s;
+        # gv replicated, as the step returns it
+        spec = jax.sharding.PartitionSpec
+        values, versions = jax.device_put(
+            (values, versions),
+            jax.sharding.NamedSharding(mesh, spec(mesh.axis_names[0])))
+        gv = jax.device_put(gv, jax.sharding.NamedSharding(mesh, spec()))
+    return ShardedStore(values=values, versions=versions, gv=gv,
+                        n_objects=store.n_objects, mesh=mesh)
 
 
 def unshard_store(store) -> TStore:

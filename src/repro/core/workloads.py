@@ -12,6 +12,7 @@ All generators are seeded and fully deterministic.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -28,10 +29,45 @@ class Workload:
     slot: int = 1
 
 
+# Below this skew numpy's Zipf rejection sampler slows down sharply
+# (~1 ms per draw at 1e-6) and stops returning at all near 1e-7, where
+# its float64 acceptance test degenerates to 0/0.
+_ZIPF_CDF_BELOW = 1e-3
+# numpy draws Zipf ranks on [1, 2**63 - 1], rejecting larger ones
+_ZIPF_RANK_MAX = 2.0 ** 63
+
+
+@functools.lru_cache(maxsize=8)
+def _zipf_clamped_cdf(n_objects: int, skew: float) -> np.ndarray:
+    """Unnormalised CDF over addresses of ``min(rank - 1, n_objects - 1)``
+    for a rank from the law numpy's ``zipf(1 + skew)`` samples: weight
+    k**-(1 + skew) for rank k in [1, 2**63 - 1].  The last address takes
+    the whole tail from rank ``n_objects`` on, summed by Euler-Maclaurin;
+    the integral term is written with ``expm1`` so it stays exact as
+    ``skew`` goes to 0."""
+    a = 1.0 + skew
+    head = np.arange(1, n_objects, dtype=np.float64) ** -a
+    lo, hi = float(n_objects), _ZIPF_RANK_MAX
+    integral = (np.expm1(-skew * np.log(lo))
+                - np.expm1(-skew * np.log(hi))) / skew
+    tail = (integral + (lo ** -a + hi ** -a) / 2
+            + a * (lo ** (-a - 1) - hi ** (-a - 1)) / 12)
+    return np.cumsum(np.append(head, tail))
+
+
 def _zipf_addrs(rng, n, n_objects, skew):
-    """Contention knob: skew=0 -> uniform; higher -> hotter hot-set."""
-    if skew <= 0:
+    """Contention knob: skew=0 -> uniform; higher -> hotter hot-set.
+
+    A skew too small to move the Zipf exponent off 1.0 in float64 (e.g.
+    5e-324) is uniform too: numpy's zipf needs an exponent above 1.  A
+    small skew above that draws the clamped law by inverse CDF, where
+    numpy's sampler would stall."""
+    if 1.0 + skew <= 1.0:
         return rng.integers(0, n_objects, size=n)
+    if skew < _ZIPF_CDF_BELOW:
+        cdf = _zipf_clamped_cdf(int(n_objects), float(skew))
+        addrs = np.searchsorted(cdf, rng.random(n) * cdf[-1], side="right")
+        return np.minimum(addrs, n_objects - 1)
     ranks = rng.zipf(1.0 + skew, size=n)
     return np.minimum(ranks - 1, n_objects - 1)
 

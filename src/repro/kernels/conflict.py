@@ -97,7 +97,7 @@ def _conflict_delta_kernel(rowlive_ref, collive_ref, foot_ref, write_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def conflict_matrix_bits_delta(foot_bits: jax.Array, write_bits: jax.Array,
                                old: jax.Array, live: jax.Array,
-                               *, interpret: bool = False) -> jax.Array:
+                               *, interpret: bool) -> jax.Array:
     """Incremental (K, K) conflict update: recompute only the rows and
     columns of live (re-executed) transactions, carry ``old`` elsewhere.
 
@@ -130,7 +130,7 @@ def conflict_matrix_bits_delta(foot_bits: jax.Array, write_bits: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def conflict_matrix_bits_pair(foot_bits: jax.Array, write_bits: jax.Array,
-                              *, interpret: bool = False) -> jax.Array:
+                              *, interpret: bool) -> jax.Array:
     """Rectangular bitset intersection: out (M, N) bool with
     out[i, j] = any_w(foot_bits[i, w] & write_bits[j, w]), for
     foot_bits (M, W) vs write_bits (N, W) over DIFFERENT row sets.
@@ -161,7 +161,7 @@ def conflict_matrix_bits_pair(foot_bits: jax.Array, write_bits: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def conflict_matrix_bits(foot_bits: jax.Array, write_bits: jax.Array,
-                         *, interpret: bool = False) -> jax.Array:
+                         *, interpret: bool) -> jax.Array:
     """conflict (K, K) bool — foot_bits (K, W) int32, write_bits (K, W) int32.
 
     K must be a multiple of lcm(BI, BJ) and W a multiple of BW (callers

@@ -85,7 +85,7 @@ def _hp_vector(lr, b1, b2, eps, wd, step, rv=0.0):
                    static_argnames=("lr", "b1", "b2", "eps", "wd",
                                     "interpret"))
 def fused_adamw(p, m, v, g, *, step, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
-                wd=0.01, interpret: bool = True):
+                wd=0.01, interpret: bool):
     """Fast-mode (direct update) fused AdamW.  p/m/v f32 (R, C), g f32/bf16.
 
     R % BR == 0 and C % BC == 0 (ops.py pads/reshapes arbitrary pytrees).
@@ -119,7 +119,7 @@ def fused_adamw(p, m, v, g, *, step, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
                                     "interpret"))
 def fused_adamw_speculative(p, m, v, g, versions, rv, *, step, lr=1e-3,
                             b1=0.9, b2=0.999, eps=1e-8, wd=0.01,
-                            interpret: bool = True):
+                            interpret: bool):
     """Speculative-mode update: per-block version validation against rv.
 
     versions: (R//BR, C//BC) int32 block versions.  Returns

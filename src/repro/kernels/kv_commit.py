@@ -50,7 +50,7 @@ def _kv_commit_kernel(page_idx_ref, row_idx_ref, sn_ref, commit_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kv_commit(cache, versions, rows, page_idx, row_idx, sn, commit,
-              *, interpret: bool = True):
+              *, interpret: bool):
     """Apply one decode step's slot commits to the paged KV cache.
 
     cache:    (P, page, H)  — paged cache (one head-group flattened to H)

@@ -31,6 +31,21 @@ def _pad_to(x, mult, axis, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _packed_intersects(foot_bits: jax.Array,
+                       write_bits: jax.Array) -> jax.Array:
+    """(M, N) bool, entry (i, j) = any_w(foot_bits[i, w] & write_bits[j, w])
+    for packed (M, W) / (N, W) words: the rectangular bitset-intersection
+    Pallas kernel on TPU, dense bit-ops off it."""
+    m, n = foot_bits.shape[0], write_bits.shape[0]
+    if _on_tpu():
+        fb = _pad_to(_pad_to(foot_bits, _conf.BI, 0), _conf.BW, 1)
+        wb = _pad_to(_pad_to(write_bits, _conf.BJ, 0), _conf.BW, 1)
+        return _conf.conflict_matrix_bits_pair(
+            fb, wb, interpret=False)[:m, :n]
+    return ((foot_bits[:, None, :] & write_bits[None, :, :]) != 0
+            ).any(axis=2)
+
+
 def validate(read_addrs: jax.Array, read_n: jax.Array,
              written_addrs: jax.Array, written_n: jax.Array,
              n_objects: int) -> jax.Array:
@@ -80,16 +95,8 @@ def conflict_matrix(raddrs: jax.Array, rn: jax.Array, waddrs: jax.Array,
     """
     if not _on_tpu():
         return _conflict_matrix_dense(raddrs, rn, waddrs, wn, n_objects)
-    k = raddrs.shape[0]
-    read_bits = _val.pack_addr_sets(raddrs, rn, n_objects)
-    write_bits = _val.pack_addr_sets(waddrs, wn, n_objects)
-    foot_bits = read_bits | write_bits
-    # pad rows to the larger of the two row-block sizes, words to BW
-    rows = max(_conf.BI, _conf.BJ)
-    foot_bits = _pad_to(_pad_to(foot_bits, rows, 0), _conf.BW, 1)
-    write_bits = _pad_to(_pad_to(write_bits, rows, 0), _conf.BW, 1)
-    out = _conf.conflict_matrix_bits(foot_bits, write_bits, interpret=False)
-    return out[:k, :k]
+    return _packed_intersects(*packed_footprints(raddrs, rn, waddrs, wn,
+                                                 n_objects))
 
 
 def packed_footprints(raddrs: jax.Array, rn: jax.Array, waddrs: jax.Array,
@@ -164,23 +171,8 @@ def conflict_matrix_delta_compact(foot_bits: jax.Array,
     (:func:`update_packed_footprints_compact`).
     """
     k = foot_bits.shape[0]
-    c = idx.shape[0]
-    cfoot = foot_bits[idx]
-    cwrite = write_bits[idx]
-    if _on_tpu():
-        fb = _pad_to(_pad_to(foot_bits, _conf.BI, 0), _conf.BW, 1)
-        wb = _pad_to(_pad_to(write_bits, _conf.BJ, 0), _conf.BW, 1)
-        cf = _pad_to(_pad_to(cfoot, _conf.BI, 0), _conf.BW, 1)
-        cw = _pad_to(_pad_to(cwrite, _conf.BJ, 0), _conf.BW, 1)
-        row_strip = _conf.conflict_matrix_bits_pair(
-            cf, wb, interpret=False)[:c, :k]
-        col_strip = _conf.conflict_matrix_bits_pair(
-            fb, cw, interpret=False)[:k, :c]
-    else:
-        row_strip = ((cfoot[:, None, :] & write_bits[None, :, :]) != 0
-                     ).any(axis=2)
-        col_strip = ((foot_bits[:, None, :] & cwrite[None, :, :]) != 0
-                     ).any(axis=2)
+    row_strip = _packed_intersects(foot_bits[idx], write_bits)
+    col_strip = _packed_intersects(foot_bits, write_bits[idx])
     from repro.core.txn import scatter_rows
     new = scatter_rows(old, row_strip, idx, valid)
     # column twin of scatter_rows: same sentinel-drop contract, axis 1
@@ -203,20 +195,17 @@ def conflict_matrix_delta(foot_bits: jax.Array, write_bits: jax.Array,
     packed sets ALREADY refreshed for live rows.
     """
     k = foot_bits.shape[0]
-    on_tpu = _on_tpu()
-    rows = max(_conf.BI, _conf.BJ)
-    fb = _pad_to(_pad_to(foot_bits, rows, 0), _conf.BW, 1)
-    wb = _pad_to(_pad_to(write_bits, rows, 0), _conf.BW, 1)
-    kp = fb.shape[0]
-    old_p = _pad_to(_pad_to(old.astype(jnp.int32), rows, 0), rows, 1)
-    live_p = _pad_to(live.astype(jnp.int32), rows, 0)
-    if on_tpu:
+    if _on_tpu():
+        rows = max(_conf.BI, _conf.BJ)
+        fb = _pad_to(_pad_to(foot_bits, rows, 0), _conf.BW, 1)
+        wb = _pad_to(_pad_to(write_bits, rows, 0), _conf.BW, 1)
+        old_p = _pad_to(_pad_to(old.astype(jnp.int32), rows, 0), rows, 1)
+        live_p = _pad_to(live.astype(jnp.int32), rows, 0)
         out = _conf.conflict_matrix_bits_delta(fb, wb, old_p, live_p,
                                                interpret=False)
         return out[:k, :k] != 0
     # dense fallback: full bitset "matmul", then carry stale entries
-    hit = (fb[:, None, :] & wb[None, :, :]) != 0
-    fresh = hit.any(axis=2)[:k, :k]
+    fresh = _packed_intersects(foot_bits, write_bits)
     refresh = live[:, None].astype(bool) | live[None, :].astype(bool)
     return jnp.where(refresh, fresh, old)
 
@@ -235,10 +224,11 @@ def conflict_matrix_delta(foot_bits: jax.Array, write_bits: jax.Array,
 #
 # because the shards partition the address space.  Every function below
 # is the per-shard twin of a dense one above, OR-reducing S independent
-# intersections (the TPU path runs one bitset kernel per shard — each a
-# candidate for its own device — and off-TPU a per-shard dense bit-ops
-# fallback); verdicts are bit-identical to the dense formulation
-# (asserted in tests/test_sharded_store.py).
+# intersections through :func:`_or_over_shards` — one bitset kernel per
+# shard, on the shard's own device under ``jax.shard_map`` when the
+# layout carries a mesh (a loop on one device otherwise), dense bit-ops
+# off-TPU; verdicts are bit-identical to the dense formulation (asserted
+# in tests/test_sharded_store.py).
 
 
 def packed_footprints_sharded(raddrs: jax.Array, rn: jax.Array,
@@ -302,58 +292,58 @@ def update_packed_footprints_compact_sharded(foot_bits: jax.Array,
         scatter(write_bits, cwrite, idx, valid)
 
 
-def _shard_intersects(foot_s: jax.Array, write_s: jax.Array) -> jax.Array:
-    """One shard's (K, K) intersection verdicts from packed words."""
-    return ((foot_s[:, None, :] & write_s[None, :, :]) != 0).any(axis=2)
+def _or_over_shards(mesh, per_shard, stacked: tuple, *replicated):
+    """OR-reduce the bool array ``per_shard(*shard_slices, *replicated)``
+    over the S shards of the ``stacked`` (S, ...) arrays.
 
-
-def conflict_matrix_sharded(foot_bits: jax.Array,
-                            write_bits: jax.Array) -> jax.Array:
-    """(K, K) conflict table from per-shard packed sets (S, K, W_s):
-    the OR over shards of each shard's bitset intersection.  TPU runs
-    the tiled Pallas kernel once per shard (W axis = W_s, not W);
-    off-TPU a per-shard dense bit-ops reduction (looped, so peak memory
-    is one shard's (K, K, W_s) tile, not S of them)."""
-    s, k, _ = foot_bits.shape
-    if _on_tpu():
-        rows = max(_conf.BI, _conf.BJ)
-        out = jnp.zeros((k, k), bool)
-        for i in range(s):
-            fb = _pad_to(_pad_to(foot_bits[i], rows, 0), _conf.BW, 1)
-            wb = _pad_to(_pad_to(write_bits[i], rows, 0), _conf.BW, 1)
-            out = out | _conf.conflict_matrix_bits(
-                fb, wb, interpret=False)[:k, :k]
+    Without a mesh the shards run as a loop on one device (peak memory
+    one shard's working set).  With the store's 1-axis mesh of S devices
+    the loop is one ``jax.shard_map``: each device runs ``per_shard`` on
+    the shard it holds and the verdicts are OR-reduced across the axis
+    (a psum of 0/1 counts) — the kernels see only device-local words, so
+    no Pallas call is left for XLA to partition.  ``check_vma`` is off:
+    the per-shard bodies (Pallas calls, the packing loop) build their
+    outputs and loop carries from unvarying zeros."""
+    if mesh is None:
+        out = None
+        for i in range(stacked[0].shape[0]):
+            v = per_shard(*(x[i] for x in stacked), *replicated)
+            out = v if out is None else out | v
         return out
-    out = jnp.zeros((k, k), bool)
-    for i in range(s):
-        out = out | _shard_intersects(foot_bits[i], write_bits[i])
-    return out
+    axis = mesh.axis_names[0]
+    spec = jax.sharding.PartitionSpec
+    n = len(stacked)
+
+    def body(*args):
+        v = per_shard(*(x[0] for x in args[:n]), *args[n:])
+        return jax.lax.psum(v.astype(jnp.int32), axis) > 0
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(spec(axis),) * n + (spec(),) * len(replicated),
+        out_specs=spec(), check_vma=False)(*stacked, *replicated)
+
+
+def conflict_matrix_sharded(foot_bits: jax.Array, write_bits: jax.Array,
+                            mesh=None) -> jax.Array:
+    """(K, K) conflict table from per-shard packed sets (S, K, W_s): the
+    OR over shards of each shard's bitset intersection (W axis = W_s,
+    not W) — one device per shard under ``mesh``."""
+    return _or_over_shards(mesh, _packed_intersects, (foot_bits, write_bits))
 
 
 def conflict_matrix_delta_sharded(foot_bits: jax.Array,
                                   write_bits: jax.Array, old: jax.Array,
                                   live: jax.Array, layout) -> jax.Array:
-    """Sharded twin of :func:`conflict_matrix_delta`: recompute entry
-    (i, j) iff i or j is live, as the OR over shards of per-shard
-    verdicts; stale entries carry ``old``.  On TPU each shard runs the
-    masked-row delta kernel against ``old`` (a stale tile ORs to itself,
-    a refreshed one to the OR of shard-fresh verdicts); off-TPU the
-    per-shard dense reduction + recompute-and-select."""
-    s, k, _ = foot_bits.shape
-    if _on_tpu():
-        rows = max(_conf.BI, _conf.BJ)
-        old_p = _pad_to(_pad_to(old.astype(jnp.int32), rows, 0), rows, 1)
-        live_p = _pad_to(live.astype(jnp.int32), rows, 0)
-        out = jnp.zeros_like(old_p)
-        for i in range(s):
-            fb = _pad_to(_pad_to(foot_bits[i], rows, 0), _conf.BW, 1)
-            wb = _pad_to(_pad_to(write_bits[i], rows, 0), _conf.BW, 1)
-            out = out | _conf.conflict_matrix_bits_delta(
-                fb, wb, old_p, live_p, interpret=False)
-        return out[:k, :k] != 0
-    fresh = conflict_matrix_sharded(foot_bits, write_bits)
-    refresh = live[:, None] | live[None, :]
-    return jnp.where(refresh, fresh, old)
+    """Sharded twin of :func:`conflict_matrix_delta`: each shard runs the
+    dense delta update on its own words against ``old``.  A stale entry
+    is ``old`` in every shard, so it ORs to itself; a refreshed one ORs
+    to the OR of the shard-fresh verdicts — the global verdict."""
+    return _or_over_shards(
+        layout.mesh,
+        lambda fb, wb, old, live: conflict_matrix_delta(
+            fb, wb, old, live, layout.shard_size),
+        (foot_bits, write_bits), old, live)
 
 
 def conflict_matrix_delta_compact_sharded(foot_bits: jax.Array,
@@ -361,40 +351,17 @@ def conflict_matrix_delta_compact_sharded(foot_bits: jax.Array,
                                           old: jax.Array, idx: jax.Array,
                                           valid: jax.Array,
                                           layout) -> jax.Array:
-    """Sharded twin of :func:`conflict_matrix_delta_compact`: the round's
-    two refreshed strips — rows idx (C, K) and columns idx (K, C) — are
-    each the OR over shards of per-shard strips (rectangular pair kernel
-    on TPU, dense bit-ops off it), scattered over last round's table.
-    ``foot_bits``/``write_bits`` (S, K, W_s) must already hold the
-    refreshed live rows (:func:`update_packed_footprints_compact_sharded`).
-    """
-    from repro.core.txn import scatter_rows
-    s, k, _ = foot_bits.shape
-    c = idx.shape[0]
-    row_strip = jnp.zeros((c, k), bool)
-    col_strip = jnp.zeros((k, c), bool)
-    if _on_tpu():
-        for i in range(s):
-            fb = _pad_to(_pad_to(foot_bits[i], _conf.BI, 0), _conf.BW, 1)
-            wb = _pad_to(_pad_to(write_bits[i], _conf.BJ, 0), _conf.BW, 1)
-            cf = _pad_to(_pad_to(foot_bits[i][idx], _conf.BI, 0),
-                         _conf.BW, 1)
-            cw = _pad_to(_pad_to(write_bits[i][idx], _conf.BJ, 0),
-                         _conf.BW, 1)
-            row_strip = row_strip | _conf.conflict_matrix_bits_pair(
-                cf, wb, interpret=False)[:c, :k]
-            col_strip = col_strip | _conf.conflict_matrix_bits_pair(
-                fb, cw, interpret=False)[:k, :c]
-    else:
-        for i in range(s):
-            row_strip = row_strip | _shard_intersects(
-                foot_bits[i][idx], write_bits[i])
-            col_strip = col_strip | _shard_intersects(
-                foot_bits[i], write_bits[i][idx])
-    new = scatter_rows(old, row_strip, idx, valid)
-    # column twin of scatter_rows: same sentinel-drop contract, axis 1
-    tgt = jnp.where(valid, idx, k)
-    return new.at[:, tgt].set(col_strip, mode="drop")
+    """Sharded twin of :func:`conflict_matrix_delta_compact`: each shard
+    scatters its own (C, K) / (K, C) strips over ``old``; the OR over
+    shards leaves untouched entries at ``old`` and makes every refreshed
+    row/column the OR of the shard strips.  ``foot_bits``/``write_bits``
+    (S, K, W_s) must already hold the refreshed live rows
+    (:func:`update_packed_footprints_compact_sharded`)."""
+    return _or_over_shards(
+        layout.mesh,
+        lambda fb, wb, old, idx, valid: conflict_matrix_delta_compact(
+            fb, wb, old, idx, valid, layout.shard_size),
+        (foot_bits, write_bits), old, idx, valid)
 
 
 # --------------------------------------------------------------------------
@@ -458,16 +425,13 @@ def spec_read_invalid(raddrs: jax.Array, rn: jax.Array,
     (``versions > snap_gv``).  On TPU the dirty words form a 1-row
     write set and the verdict is a (K, 1) strip of the rectangular
     bitset-intersection kernel; off-TPU a dense version gather."""
-    k, length = raddrs.shape
     if not _on_tpu():
-        valid = jnp.arange(length)[None, :] < rn[:, None]
+        valid = jnp.arange(raddrs.shape[1])[None, :] < rn[:, None]
         dirty = versions.reshape(-1)[:n_objects] > snap_gv
         return (valid & dirty[raddrs]).any(axis=1)
     read_bits = _val.pack_addr_sets(raddrs, rn, n_objects)
     dwords = spec_dirty_words(versions, snap_gv, n_objects)
-    rb = _pad_to(_pad_to(read_bits, _conf.BI, 0), _conf.BW, 1)
-    db = _pad_to(_pad_to(dwords[None, :], _conf.BJ, 0), _conf.BW, 1)
-    return _conf.conflict_matrix_bits_pair(rb, db, interpret=False)[:k, 0]
+    return _packed_intersects(read_bits, dwords[None, :])[:, 0]
 
 
 def spec_read_invalid_sharded(raddrs: jax.Array, rn: jax.Array,
@@ -477,23 +441,19 @@ def spec_read_invalid_sharded(raddrs: jax.Array, rn: jax.Array,
     against per-shard dirty words, OR-reduced — the PR 5 OR-over-shards
     invariant (shards partition the address space, so a dirty read hit
     lands in exactly one shard's strip)."""
-    k, length = raddrs.shape
     c = layout.shard_size
-    slotv = jnp.arange(length)[None, :] < rn[:, None]
+    slotv = jnp.arange(raddrs.shape[1])[None, :] < rn[:, None]
     dwords = spec_dirty_words_sharded(versions, snap_gv, layout)
-    out = jnp.zeros((k,), bool)
-    for s in range(layout.shards):
+
+    def per_shard(s, dwords_s, raddrs, slotv):
         rb = _val.pack_addr_sets_masked(
             raddrs - s * c, slotv & (raddrs // c == s), c)
-        if _on_tpu():
-            rbp = _pad_to(_pad_to(rb, _conf.BI, 0), _conf.BW, 1)
-            db = _pad_to(_pad_to(dwords[s][None, :], _conf.BJ, 0),
-                         _conf.BW, 1)
-            out = out | _conf.conflict_matrix_bits_pair(
-                rbp, db, interpret=False)[:k, 0]
-        else:
-            out = out | ((rb & dwords[s][None, :]) != 0).any(axis=1)
-    return out
+        return _packed_intersects(rb, dwords_s[None, :])[:, 0]
+
+    return _or_over_shards(
+        layout.mesh, per_shard,
+        (jnp.arange(layout.shards, dtype=jnp.int32), dwords),
+        raddrs, slotv)
 
 
 def cross_conflicts(reader_raddrs: jax.Array, reader_rn: jax.Array,
@@ -513,19 +473,12 @@ def cross_conflicts(reader_raddrs: jax.Array, reader_rn: jax.Array,
     nor the delta strips apply.  On TPU both sides bit-pack and the
     strip is one ``conflict.conflict_matrix_bits_pair`` launch; off-TPU
     a dense bit-ops fallback with identical verdicts."""
-    r = reader_raddrs.shape[0]
-    c = writer_waddrs.shape[0]
     rbits = _val.pack_addr_sets(reader_raddrs, reader_rn, n_objects)
     if not reads_only:
         rbits = rbits | _val.pack_addr_sets(reader_waddrs, reader_wn,
                                             n_objects)
     wbits = _val.pack_addr_sets(writer_waddrs, writer_wn, n_objects)
-    if _on_tpu():
-        rb = _pad_to(_pad_to(rbits, _conf.BI, 0), _conf.BW, 1)
-        wb = _pad_to(_pad_to(wbits, _conf.BJ, 0), _conf.BW, 1)
-        return _conf.conflict_matrix_bits_pair(
-            rb, wb, interpret=False)[:r, :c]
-    return ((rbits[:, None, :] & wbits[None, :, :]) != 0).any(axis=2)
+    return _packed_intersects(rbits, wbits)
 
 
 def adamw_update(p, m, v, g, *, step, lr=1e-3, b1=0.9, b2=0.999,

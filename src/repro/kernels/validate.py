@@ -41,7 +41,7 @@ def _validate_kernel(read_ref, written_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def validate_bitsets(read_bits: jax.Array, written_bits: jax.Array,
-                     *, interpret: bool = True) -> jax.Array:
+                     *, interpret: bool) -> jax.Array:
     """conflict (K,) bool — read_bits (K, W) int32, written_bits (W,) int32.
 
     K must be a multiple of BK and W a multiple of BW (callers pad; see

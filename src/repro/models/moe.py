@@ -122,8 +122,6 @@ def moe_apply(p, x, cfg: ModelConfig, prof: Profile):
 
 def _moe_shardmap(p, x, cfg: ModelConfig, prof: Profile):
     """Explicit GShard schedule (see module docstring)."""
-    from jax.experimental.shard_map import shard_map
-
     e, k = cfg.n_experts, cfg.top_k
     da, ma = prof.da, prof.ma
     mesh = prof.mesh
@@ -162,9 +160,9 @@ def _moe_shardmap(p, x, cfg: ModelConfig, prof: Profile):
     n_ma = mesh.shape[prof.model_axis]
     seq_ax = ma if (ma is not None and x.shape[1] % n_ma == 0
                     and x.shape[1] >= n_ma) else None
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(da, seq_ax, None), P(None, None)) + w_in_spec,
         out_specs=P(da, seq_ax, None),
-        check_rep=False)
+        check_vma=False)
     return f(x, p["router"], p["w1"], p["w3"], p["w2"])

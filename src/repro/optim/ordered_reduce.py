@@ -28,12 +28,7 @@ def ordered_ring_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     Must run inside shard_map.  x: the local shard's contribution.
     Equivalent to lax.psum(x, axis_name) with a fixed summation order.
     """
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        # pre-axis_size jax: psum of a Python constant folds to the
-        # static axis size (needed concretely for the unrolled ring).
-        n = int(jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

@@ -149,7 +149,6 @@ def make_pot_dp_step(cfg: ModelConfig, mesh, *, axis="data",
     arrival order / stragglers), and every shard applies the identical
     fast-mode commit.  Params/opt replicated (pure DP)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     prof = Profile(enabled=False)
     upd = adamw_update if optimizer == "adamw" else adafactor_update
@@ -189,8 +188,8 @@ def make_pot_dp_step(cfg: ModelConfig, mesh, *, axis="data",
     def step(state: TrainState, batch):
         sspec = jax.tree.map(lambda _: P(), state)
         bspec = jax.tree.map(lambda _: P(axis), batch)
-        f = shard_map(local_step, mesh=mesh, in_specs=(sspec, bspec),
-                      out_specs=(sspec, P()), check_rep=False)
+        f = jax.shard_map(local_step, mesh=mesh, in_specs=(sspec, bspec),
+                      out_specs=(sspec, P()), check_vma=False)
         return f(state, batch)
 
     return step

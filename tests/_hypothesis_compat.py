@@ -8,7 +8,7 @@ the deterministic tests keep running.
 """
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ModuleNotFoundError:
     import pytest
@@ -20,6 +20,8 @@ except ModuleNotFoundError:
 
     def settings(*_a, **_k):
         return lambda fn: fn
+
+    example = settings
 
     class _StrategiesStub:
         @staticmethod

@@ -30,7 +30,6 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 
 import numpy as np
 import pytest
@@ -190,10 +189,9 @@ def _subprocess_env():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    # share one persistent XLA compile cache across the victim /
-    # recovery processes — the matrix is compile-bound otherwise
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(tempfile.gettempdir(), "repro_jax_pcache"))
+    # the victim / recovery processes share the checkout's persistent
+    # compile cache (repro.launch.compile_cache) — the matrix is
+    # compile-bound otherwise; cache every step, however quick
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     return env
 

@@ -10,7 +10,7 @@ dry-run cannot (e.g. psum-ing partials across different token sets).
 One or two tokens may flip their top-k expert choice between the two
 paths: the router logits are computed under different reduction orders,
 and a bf16 tie resolves differently.  Such a token gets a *different
-but valid* expert mix (observed: 1 token of 128 on jax 0.4.37), so the
+but valid* expert mix (observed: 1 token of 128), so the
 elementwise check allows outliers confined to at most 2 whole tokens —
 every other token must pass the bf16 tolerance exactly.  A
 dispatch-layout bug corrupts whole token SETS (a capacity slice, a
@@ -28,13 +28,12 @@ import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.models import moe
+from repro.launch.mesh import make_mesh
 from repro.runtime.shardings import Profile, SMOKE
 
 cfg = get_smoke_config("deepseek_moe_16b")
 cfg = dataclasses.replace(cfg, capacity_factor=16.0)  # no drops
-# jax 0.4.x: make_mesh has no axis_types (added in 0.5); default Auto
-# axis semantics are what this test wants anyway
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 prof = Profile(data_axes=("data",), model_axis="model", mesh=mesh)
 
 key = jax.random.PRNGKey(0)
@@ -45,8 +44,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (b, s, cfg.d_model),
 
 dense = moe.moe_apply(p, x, cfg, SMOKE)
 
-# jax 0.4.x: no jax.set_mesh; entering the mesh context is equivalent here
-with mesh:
+with jax.set_mesh(mesh):
     sharded = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, prof))(p, x)
 
 a = np.asarray(dense, np.float32)

@@ -158,6 +158,11 @@ class TestStoreLayout:
         store = make_store(16)
         with pytest.raises(ValueError):
             PotSession(store=shard_store(store, 2), shards=4)
+        # jax.make_mesh's default Explicit axes break the sharded scatter
+        explicit = jax.make_mesh((1,), ("shard",), axis_types=(
+            jax.sharding.AxisType.Explicit,))
+        with pytest.raises(ValueError, match="Auto axis"):
+            shard_store(store, 1, mesh=explicit)
         with pytest.raises(ValueError):
             PotSession(16, bucket_ladder="golden")
 
@@ -440,6 +445,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import (PotSession, RoundRobinSequencer, fingerprint,
                         make_store, pcc_execute, shard_store)
 from repro.core import workloads as W
+from repro.launch.mesh import make_host_mesh
 
 wl = W.counters(n_txns=24, n_objects=80, n_reads=2, n_writes=2,
                 n_lanes=4, skew=0.9, seed=6)
@@ -450,7 +456,7 @@ out_d, tr_d = pcc_execute(dense, wl.batch, seq)
 for s in (1, 2, 8):
     # s=1 with a mesh: a single-shard ShardedStore must still route
     # through the shard_map path (regression: generic shards=len(devices))
-    mesh = jax.make_mesh((s,), ("shard",), devices=jax.devices()[:s])
+    mesh = make_host_mesh(s, "shard")
     out_s, tr_s = pcc_execute(shard_store(dense, s, mesh=mesh),
                               wl.batch, seq)
     assert int(fingerprint(out_s)) == int(fingerprint(out_d)), s
@@ -458,7 +464,7 @@ for s in (1, 2, 8):
                           np.asarray(tr_d.commit_pos)), s
 # session-level: mesh store threads through the cached jitted step
 sess = PotSession(80, engine="pcc", n_lanes=4, shards=8,
-                  mesh=jax.make_mesh((8,), ("shard",)))
+                  mesh=make_host_mesh(8, "shard"))
 sess.submit(wl.batch, wl.lanes.tolist())
 ref = PotSession(80, engine="pcc", n_lanes=4)
 ref.submit(wl.batch, wl.lanes.tolist())
@@ -478,3 +484,65 @@ def test_shard_map_mesh_equals_dense():
                        capture_output=True, text=True, cwd=repo,
                        env={**os.environ, "PYTHONPATH": "src"})
     assert "MESH_OK" in r.stdout, (r.stdout[-1000:], r.stderr[-3000:])
+
+
+TWINS_CODE = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
+from repro.core import StoreLayout, run_all
+from repro.core import workloads as W
+from repro.core.txn import gather_live_indices
+from repro.kernels import ops
+from repro.launch.mesh import make_host_mesh
+
+n, k = 90, 24
+mesh = make_host_mesh(4, "shard")
+layout = StoreLayout(n, 4, mesh)
+wl = W.counters(n_txns=k, n_objects=n, n_reads=2, n_writes=2, n_lanes=4,
+                skew=0.5, seed=2)
+rng = np.random.default_rng(0)
+res = run_all(wl.batch, jnp.asarray(rng.integers(0, n, (n, 1)), jnp.int32))
+dense = np.asarray(ops._conflict_matrix_dense(res.raddrs, res.rn,
+                                              res.waddrs, res.wn, n))
+foot, write = ops.packed_footprints_sharded(res.raddrs, res.rn, res.waddrs,
+                                            res.wn, StoreLayout(n, 4))
+# each shard's words on its own device, as the sharded store holds them
+on_mesh = NamedSharding(mesh, PartitionSpec("shard"))
+foot, write = jax.device_put((foot, write), on_mesh)
+full = ops.conflict_matrix_sharded(foot, write, mesh)
+assert (np.asarray(full) == dense).all()
+stale = jnp.zeros((k, k), bool)
+live = jnp.asarray(rng.random(k) < 0.4)
+delta = jax.jit(ops.conflict_matrix_delta_sharded, static_argnums=4)(
+    foot, write, stale, live, layout)
+lv = np.asarray(live)
+assert (np.asarray(delta) == (dense & (lv[:, None] | lv[None, :]))).all()
+idx, valid = gather_live_indices(live, 16)
+compact = jax.jit(ops.conflict_matrix_delta_compact_sharded,
+                  static_argnums=5)(foot, write, stale, idx, valid, layout)
+assert (np.asarray(compact) == np.asarray(delta)).all()
+versions = jnp.asarray(rng.integers(0, 100, (n,)), jnp.int32)
+snap = jnp.asarray(60, jnp.int32)
+stacked = jax.device_put(jnp.pad(versions, (0, layout.padded_objects - n))
+                         .reshape(4, layout.shard_size), on_mesh)
+got = ops.spec_read_invalid_sharded(res.raddrs, res.rn, stacked, snap, layout)
+want = ops.spec_read_invalid(res.raddrs, res.rn, versions, snap, n)
+assert (np.asarray(got) == np.asarray(want)).all()
+print("TWINS_OK")
+"""
+
+
+def test_shard_map_conflict_twins_equal_dense():
+    """The per-shard conflict twins under jax.shard_map on a 4-device
+    host-platform mesh — one shard's words per device, verdicts
+    OR-reduced across the mesh axis — equal the dense verdicts: full
+    table, masked delta, compact strips and the cross-batch validation
+    strip."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", TWINS_CODE],
+                       capture_output=True, text=True, cwd=repo,
+                       env={**os.environ, "PYTHONPATH": "src"})
+    assert "TWINS_OK" in r.stdout, (r.stdout[-1000:], r.stderr[-3000:])

@@ -137,15 +137,14 @@ class TestOrderedReduce:
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.optim import ordered_ring_reduce
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(8)
 x = jnp.arange(8 * 24, dtype=jnp.float32).reshape(8, 24) / 7.0
-f = shard_map(lambda y: ordered_ring_reduce(y[0], "data")[None],
+f = jax.shard_map(lambda y: ordered_ring_reduce(y[0], "data")[None],
               mesh=mesh, in_specs=P("data", None),
-              out_specs=P("data", None), check_rep=False)
+              out_specs=P("data", None), check_vma=False)
 got = np.asarray(f(x))
 want = np.asarray(x.sum(0))
 for i in range(8):
