@@ -1,0 +1,8 @@
+"""Host microseconds per transaction inside ``IngressPool.admit`` (the
+harness's ``admit`` span), over a backlog window."""
+
+from potbench.readers import span_us_per_txn
+
+
+def read(record):
+    return span_us_per_txn(record, "admit")
