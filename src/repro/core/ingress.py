@@ -17,7 +17,10 @@ admission counter, or a caller-supplied logical time), "age" is a stamp
 difference, and priorities are integer arithmetic over (fee, age, size).
 This is what makes an :class:`IngressPool` a pure state machine — its
 entire behavior is a function of the admission/drain event sequence, so
-the event journal IS the replication/replay substrate.
+the event journal IS the replication/replay substrate.  The drain's
+profiler spans (``pot.drain`` and its ``pack``/``upload`` children)
+only let a running ``jax.profiler`` trace time it; nothing they record
+flows back into pool state.
 
 The pool does four things:
 
@@ -94,10 +97,11 @@ import heapq
 from collections import deque
 from typing import Iterable, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.sequencer import RoundRobinSequencer
-from repro.core.txn import TxnBatch, make_batch, next_pow2
+from repro.core.txn import TxnBatch, next_pow2, pack_batch, upload_batch
 
 # journal event kinds (plain tuples so a journal is transport-friendly)
 EV_CONFIG, EV_SPAWN, EV_STOP, EV_ADMIT, EV_DRAIN = (
@@ -120,7 +124,7 @@ class JournalError(ValueError):
 
     Raised by :meth:`IngressPool.replay` / :meth:`IngressPool.apply`
     with the failing event's index, instead of letting a malformed
-    tuple fail deep inside drain/``make_batch`` with an opaque shape
+    tuple fail deep inside drain/``pack_batch`` with an opaque shape
     error.  The journal IS the replication substrate — a replica must
     refuse a feed it cannot prove well-formed rather than diverge.
     """
@@ -346,7 +350,7 @@ class IngressPool:
                 "padding convention and would never commit; admit a "
                 "single NOP instead")
         for i, ins in enumerate(program):
-            # fail at admission, not deep inside drain's make_batch
+            # fail at admission, not deep inside drain's pack_batch
             if len(ins) != 4:
                 raise ValueError(
                     f"program instruction {i} has {len(ins)} fields, "
@@ -443,38 +447,51 @@ class IngressPool:
         the smallest ``(-priority, lane, lane_seq)`` key.  Priorities are
         fixed for the duration of the call (stamps only advance on
         admission), so partitioning a drain prefix into budgets cannot
-        change the flat drained sequence."""
+        change the flat drained sequence.
+
+        Runs in the profiler span ``pot.drain`` (stats ``batch``, the
+        batch's first sequence number, and ``k``, the transactions it
+        takes), the host fill of the batch's arrays in
+        ``pot.drain.pack`` and their upload in ``pot.drain.upload``."""
         budget = int(budget)
         if budget < 1:
             raise ValueError(f"drain budget must be >= 1, got {budget}")
-        self._journal.append((EV_DRAIN, budget))
-        self.stats.drain_calls += 1
-        heap = [(self._drain_key(q[0]), lane)
-                for lane, q in self._queues.items() if q]
-        heapq.heapify(heap)
-        picked: list[_Entry] = []
-        while heap and len(picked) < budget:
-            _, lane = heapq.heappop(heap)
-            q = self._queues[lane]
-            picked.append(q.popleft())
-            if q:
-                heapq.heappush(heap, (self._drain_key(q[0]), lane))
-        if not picked:
-            return None
-        k = len(picked)
-        self._depth -= k
-        self.stats.drained += k
-        self._drain_sizes.append(k)
-        batch = make_batch([list(e.program) for e in picked])
-        base = self._drain_seq
-        self._drain_seq += k
-        return FormedBatch(
-            batch=batch,
-            lanes=np.asarray([e.lane for e in picked], np.int64),
-            seq=np.arange(base + 1, base + k + 1, dtype=np.int64),
-            txn_ids=np.asarray([e.txn_id for e in picked], np.int64),
-            stamps=np.asarray([e.stamp for e in picked], np.int64),
-            ladder=self.preferred_ladder(), budget=budget)
+        # every parked transaction is in some lane's queue, so the drain
+        # takes min(budget, depth) of them
+        with jax.profiler.TraceAnnotation(
+                "pot.drain", batch=self._drain_seq + 1,
+                k=min(budget, self._depth)):
+            self._journal.append((EV_DRAIN, budget))
+            self.stats.drain_calls += 1
+            heap = [(self._drain_key(q[0]), lane)
+                    for lane, q in self._queues.items() if q]
+            heapq.heapify(heap)
+            picked: list[_Entry] = []
+            while heap and len(picked) < budget:
+                _, lane = heapq.heappop(heap)
+                q = self._queues[lane]
+                picked.append(q.popleft())
+                if q:
+                    heapq.heappush(heap, (self._drain_key(q[0]), lane))
+            if not picked:
+                return None
+            k = len(picked)
+            self._depth -= k
+            self.stats.drained += k
+            self._drain_sizes.append(k)
+            with jax.profiler.TraceAnnotation("pot.drain.pack"):
+                host = pack_batch([e.program for e in picked])
+            with jax.profiler.TraceAnnotation("pot.drain.upload"):
+                batch = upload_batch(host)
+            base = self._drain_seq
+            self._drain_seq += k
+            return FormedBatch(
+                batch=batch,
+                lanes=np.asarray([e.lane for e in picked], np.int64),
+                seq=np.arange(base + 1, base + k + 1, dtype=np.int64),
+                txn_ids=np.asarray([e.txn_id for e in picked], np.int64),
+                stamps=np.asarray([e.stamp for e in picked], np.int64),
+                ladder=self.preferred_ladder(), budget=budget)
 
     def drain_all(self, budget: int) -> list[FormedBatch]:
         """Drain to empty in ``budget``-sized batches."""

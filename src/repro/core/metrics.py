@@ -254,19 +254,3 @@ def _report_occ(trace, batch, res_rn, res_wn) -> EngineReport:
         throughput=k / cp if cp else float("inf"),
         wave_trips=int(trace.wave_trips), live_txns=int(trace.live_txns))
 
-
-# -- deprecated per-engine entry points (pre-ExecTrace API) ---------------
-def report_pcc(trace, batch, res_rn, res_wn) -> EngineReport:
-    return report_from_trace("pot", trace, batch, res_rn, res_wn)
-
-
-def report_pogl(batch, res_rn, res_wn) -> EngineReport:
-    return report_from_trace("pogl", None, batch, res_rn, res_wn)
-
-
-def report_destm(trace, batch, res_rn, res_wn, n_lanes: int) -> EngineReport:
-    return report_from_trace("destm", trace, batch, res_rn, res_wn, n_lanes)
-
-
-def report_occ(trace, batch, res_rn, res_wn) -> EngineReport:
-    return report_from_trace("occ", trace, batch, res_rn, res_wn)

@@ -308,19 +308,23 @@ class PotSession:
         by the serial step and the speculative enqueue: pads the batch
         to its (K, L) bucket and extends ``seq`` / ``lane_ids`` over the
         vacant rows (sequence numbers past every real one).  Returns
-        ``(batch, seq, lane_ids, k, bk)`` with k the real row count."""
+        ``(batch, seq, lane_ids, k, bk)`` with k the real row count.
+        The padding runs in the profiler span ``pot.step.pad`` (stats
+        ``k`` and ``bk``), the bookkeeping of :meth:`_record` in
+        ``pot.step.record``."""
         k = batch.n_txns
-        seq = np.asarray(seq, np.int64)
-        lane_ids = np.asarray(lane_ids, np.int64) % max(self.n_lanes, 1)
         bk, bl = self._bucket_shape(batch, ladder)
-        self._bucket_counts[(bk, bl)] = \
-            self._bucket_counts.get((bk, bl), 0) + 1
-        if (bk, bl) != (k, batch.max_ins):
-            batch = pad_batch(batch, bk, bl)
-            base = seq.max() if k else 0
-            seq = np.concatenate([seq, base + 1 + np.arange(bk - k)])
-            lane_ids = np.concatenate(
-                [lane_ids, np.zeros((bk - k,), lane_ids.dtype)])
+        with jax.profiler.TraceAnnotation("pot.step.pad", k=k, bk=bk):
+            seq = np.asarray(seq, np.int64)
+            lane_ids = np.asarray(lane_ids, np.int64) % max(self.n_lanes, 1)
+            self._bucket_counts[(bk, bl)] = \
+                self._bucket_counts.get((bk, bl), 0) + 1
+            if (bk, bl) != (k, batch.max_ins):
+                batch = pad_batch(batch, bk, bl)
+                base = seq.max() if k else 0
+                seq = np.concatenate([seq, base + 1 + np.arange(bk - k)])
+                lane_ids = np.concatenate(
+                    [lane_ids, np.zeros((bk - k,), lane_ids.dtype)])
         return batch, seq, lane_ids, k, bk
 
     def _record(self, trace: ExecTrace, k: int, bk: int) -> ExecTrace:
@@ -328,11 +332,12 @@ class PotSession:
         the trace (kept on device — the commit order is recorded by
         keeping the trace, and replay_log() materializes it on demand,
         so no device->host sync on the streaming hot path)."""
-        if bk != k:   # slice vacant rows back off (lazy device ops)
-            trace = dataclasses.replace(trace, **{
-                f: getattr(trace, f)[:k] for f in _PER_TXN_FIELDS})
-        self._n_txns += k
-        self.traces.append(trace)
+        with jax.profiler.TraceAnnotation("pot.step.record"):
+            if bk != k:   # slice vacant rows back off (lazy device ops)
+                trace = dataclasses.replace(trace, **{
+                    f: getattr(trace, f)[:k] for f in _PER_TXN_FIELDS})
+            self._n_txns += k
+            self.traces.append(trace)
         return trace
 
     def _submit_seq(self, batch: TxnBatch, seq: np.ndarray,
@@ -392,22 +397,25 @@ class PotSession:
         worker lanes; bumps the formed-batch cursor; then submits —
         through the speculation window when pipelined.  Returns the
         traces completed by this step (possibly none while the window
-        fills)."""
-        fb_ladder = ladder if ladder is not None else fb.ladder
-        lanes = fb.lanes
-        if self.elastic is not None:
-            self.elastic.advance_to(self._batches_formed + 1)
-            lanes = np.asarray([self.elastic.worker_for(int(l))
-                                for l in np.asarray(fb.lanes)], np.int64)
-        self._batches_formed += 1
-        if self._pipelined:
-            self._spec_enqueue(fb.batch, fb.seq, lanes, ladder=fb_ladder)
-            out = []
-            while len(self._window) > self.pipeline_depth:
-                out.append(self._spec_drain())
-            return out
-        return [self._submit_seq(fb.batch, fb.seq, lanes,
-                                 ladder=fb_ladder)]
+        fills).  The step runs in the profiler span ``pot.step``, whose
+        stat ``batch`` is the formed batch's first sequence number, the
+        same as on the drain's ``pot.drain`` span."""
+        with jax.profiler.TraceAnnotation("pot.step", batch=int(fb.seq[0])):
+            fb_ladder = ladder if ladder is not None else fb.ladder
+            lanes = fb.lanes
+            if self.elastic is not None:
+                self.elastic.advance_to(self._batches_formed + 1)
+                lanes = np.asarray([self.elastic.worker_for(int(l))
+                                    for l in np.asarray(fb.lanes)], np.int64)
+            self._batches_formed += 1
+            if self._pipelined:
+                self._spec_enqueue(fb.batch, fb.seq, lanes, ladder=fb_ladder)
+                out = []
+                while len(self._window) > self.pipeline_depth:
+                    out.append(self._spec_drain())
+                return out
+            return [self._submit_seq(fb.batch, fb.seq, lanes,
+                                     ladder=fb_ladder)]
 
     def serve(self, pool, budget: int = 64, *,
               max_batches: int | None = None,

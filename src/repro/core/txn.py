@@ -36,6 +36,7 @@ round loops use — there is no separate speculation VM.
 from __future__ import annotations
 
 import dataclasses
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +88,13 @@ def make_batch(progs: list[list[tuple]], max_ins: int | None = None) -> TxnBatch
     intentionally empty transaction should be a single NOP instruction
     (``[(NOP, 0, False, 0)]``), which commits normally with an empty
     footprint."""
+    return upload_batch(pack_batch(progs, max_ins))
+
+
+def pack_batch(progs: Sequence[Sequence[tuple]],
+               max_ins: int | None = None) -> TxnBatch:
+    """The host half of :func:`make_batch`: the same (K, L) arrays as
+    NumPy arrays, filled from the programs on the host."""
     k = len(progs)
     length = max_ins or max((len(p) for p in progs), default=1)
     length = max(length, 1)
@@ -99,11 +107,14 @@ def make_batch(progs: list[list[tuple]], max_ins: int | None = None) -> TxnBatch
         n[i] = len(p)
         for j, (o, a, b, v) in enumerate(p):
             op[i, j], ad[i, j], ind[i, j], opr[i, j] = o, a, b, v
-    return TxnBatch(
-        opcodes=jnp.asarray(op), addrs=jnp.asarray(ad),
-        indirect=jnp.asarray(ind), operands=jnp.asarray(opr),
-        n_ins=jnp.asarray(n),
-    )
+    return TxnBatch(opcodes=op, addrs=ad, indirect=ind, operands=opr,
+                    n_ins=n)
+
+
+def upload_batch(batch: TxnBatch) -> TxnBatch:
+    """The device half of :func:`make_batch`: one host->device copy per
+    field of a :func:`pack_batch` result."""
+    return jax.tree.map(jnp.asarray, batch)
 
 
 def run_txn(batch_row, values: jax.Array,
