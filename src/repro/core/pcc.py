@@ -18,8 +18,8 @@ Each engine round is three *batched* stages (the shared commit pipeline,
    validation question asked for the whole batch at once
    (``protocol.earlier_writer_conflicts``): on TPU a masked
    row-reduction of the K×K footprint-conflict matrix
-   (``kernels/conflict.py``, a tiled bitset-intersection Pallas kernel
-   over bit-packed read/write sets), elsewhere a first-writer-per-
+   (``kernels/conflict.py``, a tiled Pallas kernel comparing the
+   read/write address lists), elsewhere a first-writer-per-
    address scatter-min with O(K·L) work — two decision-identical
    formulations of the same question.
 3. **Prefix fixpoint + fused write-back** — the maximal committing

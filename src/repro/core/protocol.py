@@ -18,13 +18,12 @@ persistent execution state every engine threads through its
   :func:`repro.core.txn.run_live`) and keeps the settled rows' cached
   results, so a low-contention round no longer pays a full-batch
   ``run_all`` on already-committed transactions;
-* the carried conflict structure — the K×K ``conflict`` table plus,
-  on TPU, the bit-packed footprints behind it
-  (``kernels.ops.update_packed_footprints``): only the rows/columns of
-  re-executed transactions are recomputed per round, via the
-  masked-row variant of the bitset-intersection Pallas kernel
-  (``kernels.conflict.conflict_matrix_bits_delta``; dense
-  recompute-and-select fallback off-TPU).
+* the carried conflict structure — the K×K ``conflict`` table: only
+  the rows/columns of re-executed transactions are recomputed per
+  round, on TPU by a masked Pallas kernel over the round's address
+  lists (``kernels.conflict.conflict_matrix_bits_addr_delta``; on a
+  sharded store over per-shard bit-packed footprints carried beside
+  it); dense recompute-and-select fallback off-TPU.
 
 Correctness rests on one invariant: an engine's commit decision only
 ever *consumes* conflict entries and footprint rows of transactions
@@ -42,14 +41,14 @@ fits a narrower rung C, the read phase gathers the live rows into a
 (C, L) block, executes that
 (:func:`refresh_round_state_compact` / the caller-ordered
 :func:`refresh_round_state_gathered`), and scatters results — plus the
-packed-footprint rows and the conflict table's refreshed row/column
-strips (``kernels.ops.conflict_matrix_delta_compact``, two rectangular
-bitset-intersection strips instead of a K×K pass) — back to full-K
-positions.  Commit decisions and the fused write-back stay in rank
-space, so the cascade is bit-identical to the masked loop
-(``compact=False``; asserted by tests and ``scripts/ci.sh
---compact-smoke``); only the device work changes, from K·L to C·L per
-round (``RoundState.walked_slots`` / ``ExecTrace.walked_slots``).
+conflict table's refreshed row/column strips
+(``kernels.ops.conflict_matrix_delta_compact_addrs``, two rectangular
+strips instead of a K×K pass; on a sharded store also the per-shard
+packed-footprint rows) — back to full-K positions.  Commit decisions
+and the fused write-back stay in rank space, so the cascade is
+bit-identical to the masked loop (``compact=False``; asserted by tests
+and ``scripts/ci.sh --compact-smoke``); only the device work changes,
+from K·L to C·L per round (``RoundState.walked_slots`` / ``ExecTrace.walked_slots``).
 DeSTM's ≤ n_lanes rounds are the degenerate always-compact case: its
 members run through :func:`refresh_round_state_gathered` in token
 order at width n_lanes.  Vacant rows (``n_ins == 0`` — shape-bucket
@@ -228,9 +227,10 @@ def conflict_table(res, n_objects: int,
     space: entry (i, j) = footprint(i) ∩ writes(j) ≠ ∅ (the paper's
     per-txn validation question asked for all ordered pairs at once).
 
-    Materialized only where the dense bitset-intersection kernel is the
-    right formulation (TPU, `kernels/conflict.py`; cf. validate.py's
-    dense-bitset argument).  Returns ``None`` elsewhere —
+    Materialized only on TPU, where a Pallas kernel
+    (`kernels/conflict.py`) answers every pair in one regular pass of
+    K²·2L·L address compares (the bit-packed form it replaced cost K²·W
+    word-ANDs, W = ceil(O/32)).  Returns ``None`` elsewhere —
     :func:`earlier_writer_conflicts` then uses the first-writer
     scatter-min formulation, which gives identical verdicts with O(K·L)
     work (asserted in tests/test_commit_pipeline.py).
@@ -258,26 +258,27 @@ class RoundState:
     round calls :func:`refresh_round_state` with the mask of *live*
     (still-pending) transactions and only those rows re-execute — the
     paper's abort-and-retry, restricted to the transactions it actually
-    applies to.  ``conflict`` (and on TPU the packed ``foot_bits`` /
-    ``write_bits`` behind it) is carried the same way: suffix footprints
+    applies to.  ``conflict`` is carried the same way: suffix footprints
     change only via re-execution, so only live rows/columns are
-    recomputed.  ``live_txns`` / ``live_slots`` accumulate the actual
-    re-execution work for the trace (the observable proving settled
-    transactions are skipped).
+    recomputed — from the address lists in ``res`` (K²·2L·L compares
+    at most), or, on a sharded store, from per-shard packed
+    ``foot_bits`` / ``write_bits`` carried beside it.  ``live_txns`` /
+    ``live_slots`` accumulate the actual re-execution work for the trace
+    (the observable proving settled transactions are skipped).
 
     ``conflict``/``foot_bits``/``write_bits`` are ``None`` when the
     engine uses the scatter-min conflict formulation (off-TPU default)
-    or carries no table at all (DeSTM's compact-block rounds); the
-    choice is static per trace, so the pytree structure is while_loop-
-    stable.
+    or carries no table at all (DeSTM's compact-block rounds), and the
+    bitsets alone are ``None`` unless the store is sharded; the choice
+    is static per trace, so the pytree structure is while_loop-stable.
     """
 
     values: jax.Array        # (O, S) committed store image
     versions: jax.Array      # (O,)
     res: TxnResult           # cached speculative executions (K rows)
     conflict: jax.Array | None    # (K, K) carried conflict table
-    foot_bits: jax.Array | None   # (K, W) packed footprints (TPU path)
-    write_bits: jax.Array | None  # (K, W) packed write sets (TPU path)
+    foot_bits: jax.Array | None   # (S, K, W_s) packed footprints (sharded)
+    write_bits: jax.Array | None  # (S, K, W_s) packed write sets (sharded)
     live: jax.Array          # (K,) bool — rows refreshed this round
     live_txns: jax.Array     # () int32 — Σ rounds live count
     live_slots: jax.Array    # () int32 — Σ rounds live instruction slots
@@ -328,10 +329,6 @@ def init_round_state(batch: TxnBatch, values: jax.Array,
             w = layout.words_per_shard
             foot_bits = z((layout.shards, k, w), jnp.int32)
             write_bits = z((layout.shards, k, w), jnp.int32)
-        elif kernel_ops._on_tpu():
-            w = -(-values.shape[0] // 32)
-            foot_bits = z((k, w), jnp.int32)
-            write_bits = z((k, w), jnp.int32)
     return RoundState(
         values=values, versions=versions, res=res, conflict=conflict,
         foot_bits=foot_bits, write_bits=write_bits,
@@ -376,17 +373,10 @@ def refresh_round_state(state: RoundState, batch: TxnBatch,
                     res.waddrs, res.wn, live, layout)
             conflict = kernel_ops.conflict_matrix_delta_sharded(
                 foot_bits, write_bits, conflict, live, layout)
-        elif foot_bits is not None:  # TPU: packed bitsets + masked kernel
-            foot_bits, write_bits = kernel_ops.update_packed_footprints(
-                foot_bits, write_bits, res.raddrs, res.rn, res.waddrs,
-                res.wn, live, n_obj)
-            conflict = kernel_ops.conflict_matrix_delta(
-                foot_bits, write_bits, conflict, live, n_obj)
-        else:                       # dense recompute-and-select fallback
-            fresh = kernel_ops._conflict_matrix_dense(
-                res.raddrs, res.rn, res.waddrs, res.wn, n_obj)
-            refresh = live[:, None] | live[None, :]
-            conflict = jnp.where(refresh, fresh, conflict)
+        else:                       # address lists (dense off-TPU)
+            conflict = kernel_ops.conflict_matrix_delta_addrs(
+                res.raddrs, res.rn, res.waddrs, res.wn, conflict, live,
+                n_obj)
     k, length = batch.opcodes.shape
     return RoundState(
         values=state.values, versions=state.versions, res=res,
@@ -450,9 +440,9 @@ def refresh_round_state_gathered(state: RoundState, batch: TxnBatch,
     """One round's read phase over a caller-gathered compact block: execute
     rows ``batch[idx]`` (``valid`` masks gather padding, possibly with
     duplicate indices) at width C = ``idx.shape[0]`` and scatter the
-    results — plus, when a conflict table is carried, the packed-footprint
-    rows and the table's refreshed row/column strips — back to full-K
-    positions.
+    results — plus, when a conflict table is carried, the table's
+    refreshed row/column strips (and on a sharded store the per-shard
+    packed-footprint rows) — back to full-K positions.
 
     The caller chooses the gather order: :func:`refresh_round_state_compact`
     packs live rows ascending; DeSTM passes its round members in token
@@ -486,17 +476,10 @@ def refresh_round_state_gathered(state: RoundState, batch: TxnBatch,
                     cres.waddrs, cres.wn, idx, valid, layout)
             conflict = kernel_ops.conflict_matrix_delta_compact_sharded(
                 foot_bits, write_bits, conflict, idx, valid, layout)
-        elif foot_bits is not None:  # TPU: packed strips + pair kernel
-            foot_bits, write_bits = kernel_ops.update_packed_footprints_compact(
-                foot_bits, write_bits, cres.raddrs, cres.rn, cres.waddrs,
-                cres.wn, idx, valid, n_obj)
-            conflict = kernel_ops.conflict_matrix_delta_compact(
-                foot_bits, write_bits, conflict, idx, valid, n_obj)
-        else:                       # dense recompute-and-select fallback
-            fresh = kernel_ops._conflict_matrix_dense(
-                res.raddrs, res.rn, res.waddrs, res.wn, n_obj)
-            refresh = live[:, None] | live[None, :]
-            conflict = jnp.where(refresh, fresh, conflict)
+        else:                       # address strips (dense off-TPU)
+            conflict = kernel_ops.conflict_matrix_delta_compact_addrs(
+                res.raddrs, res.rn, res.waddrs, res.wn, conflict, idx,
+                valid, n_obj)
     return RoundState(
         values=state.values, versions=state.versions, res=res,
         conflict=conflict, foot_bits=foot_bits, write_bits=write_bits,
@@ -561,8 +544,8 @@ class SpecSeed:
 
     res: TxnResult                # (K rows) speculative executions
     conflict: jax.Array | None    # (K, K) speculative conflict table
-    foot_bits: jax.Array | None   # packed footprints (TPU / sharded)
-    write_bits: jax.Array | None  # packed write sets  (TPU / sharded)
+    foot_bits: jax.Array | None   # packed footprints (sharded)
+    write_bits: jax.Array | None  # packed write sets  (sharded)
     snap_gv: jax.Array            # () int32 — store.gv at the snapshot
 
 

@@ -35,6 +35,26 @@ footprint against the live write sets — scattered over the carried
 table (ops.conflict_matrix_delta_compact): O(C·K·W) device work with no
 K² term at all, vs the masked delta's K²-shaped grid whose dead blocks
 skip work but still launch.
+
+Address lists.  The bitset form pays for the whole address space: a
+(K, K) table costs K²·W word-ANDs although a transaction touches only
+its L slots (at O = 2^20 records, W = 32,768 words against L = 16).  The
+same verdict compares the lists themselves:
+
+    conflict[i, j] = any_{a < 2L, b < L}( foot[i, a] == write[j, b] )
+
+with foot = reads then writes (2L slots) and the invalid slots replaced
+by two sentinels that no address (and neither other) equals — K²·2L·L
+compares, no (K, W) state, no lane reduction.  The address kernels
+(conflict_matrix_bits_addr_delta / _addr_pair) tile the output into
+(BT_I, BT_J) blocks, footprint rows on sublanes and write sets on lanes
+(the write lists come transposed, (L, K)), and OR-accumulate one
+broadcast compare per (a, b) slot pair: a loop over the L write slots
+whose body compares all 2L footprint slots, so the kernel's code grows
+with 2L, not 2L·L.  They build every one-chip table on TPU; the bitset
+kernels above stay for the sharded layout (each shard ANDs only its own
+range's words, ops.*_sharded) and for the cross-batch dirty-set strip
+(ops.spec_read_invalid).
 """
 
 from __future__ import annotations
@@ -48,6 +68,11 @@ from jax.experimental import pallas as pl
 BI = 8     # footprint rows per block (sublane dimension)
 BJ = 128   # write-set rows per block (lane dimension of the output tile)
 BW = 128   # bitset words per block
+
+BT_I = 128  # footprint rows per address tile (sublanes)
+BT_J = 128  # write-set rows per address tile (lanes)
+FOOT_VOID = -1    # an invalid footprint slot
+WRITE_VOID = -2   # an invalid write slot: equals no address, nor FOOT_VOID
 
 
 def _conflict_kernel(foot_ref, write_ref, out_ref):
@@ -103,7 +128,7 @@ def conflict_matrix_bits_delta(foot_bits: jax.Array, write_bits: jax.Array,
 
     foot_bits / write_bits (K, W) int32 must already hold the CURRENT
     round's packed sets (live rows refreshed, settled rows carried —
-    see ops.update_packed_footprints); ``old`` (K, K) int32 is last
+    see ops.update_packed_footprints_sharded); ``old`` (K, K) int32 is last
     round's table and ``live`` (K,) int32 flags the re-executed rows.
     Same padding contract as :func:`conflict_matrix_bits`.
     """
@@ -171,3 +196,115 @@ def conflict_matrix_bits(foot_bits: jax.Array, write_bits: jax.Array,
     """
     return conflict_matrix_bits_pair(foot_bits, write_bits,
                                      interpret=interpret)
+
+
+def _addr_hits(foot_ref, write_ref):
+    """(M, N) int32 tile: 1 where any foot[i, a] equals any write_t[b, j].
+    foot (M, A) holds row i's slots along lanes, write_t (B, N) column
+    j's slots along sublanes; one broadcast compare per (a, b), in a loop
+    over the B write slots whose body unrolls the A footprint slots."""
+    foot = foot_ref[...]
+    cols = [foot[:, a:a + 1] for a in range(foot.shape[1])]
+
+    def slot(b, hit):
+        row = write_ref[pl.ds(b, 1), :]                    # (1, N)
+        for col in cols:
+            hit = jnp.where(col == row, 1, hit)
+        return hit
+
+    hit = jnp.zeros((foot.shape[0], write_ref.shape[1]), jnp.int32)
+    return jax.lax.fori_loop(0, write_ref.shape[0], slot, hit)
+
+
+def _addr_pair_kernel(foot_ref, write_ref, out_ref):
+    out_ref[...] = _addr_hits(foot_ref, write_ref)
+
+
+def _addr_delta_kernel(rowlive_ref, collive_ref, foot_ref, write_ref,
+                       old_ref, out_ref):
+    """The refresh rule of :func:`_conflict_delta_kernel` over address
+    lists: entries whose row or column is live are recomputed, the rest
+    carry ``old``; a tile with neither skips the compares."""
+    refresh = (rowlive_ref[...] != 0) | (collive_ref[...] != 0)  # (BI, BJ)
+    out_ref[...] = old_ref[...]
+
+    @pl.when(refresh.sum() > 0)
+    def _refresh():
+        hit = _addr_hits(foot_ref, write_ref)
+        out_ref[...] = jnp.where(refresh, hit, out_ref[...])
+
+
+def _addr_tiles(m: int, n: int) -> tuple[int, int]:
+    bi, bj = min(BT_I, m), min(BT_J, n)
+    assert m % bi == 0 and bi % 8 == 0 and n % bj == 0 and bj % 128 == 0, \
+        (m, n)
+    return bi, bj
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def conflict_matrix_bits_addr_pair(foot_addrs: jax.Array,
+                                   write_addrs_t: jax.Array,
+                                   *, interpret: bool) -> jax.Array:
+    """Rectangular address-list conflict strip: out (M, N) bool with
+    out[i, j] = any_{a, b}(foot_addrs[i, a] == write_addrs_t[b, j]).
+
+    foot_addrs (M, A) int32 (a row's reads then writes, FOOT_VOID in
+    invalid slots) against write_addrs_t (B, N) int32 (write sets,
+    transposed, WRITE_VOID in invalid slots).  M must be a multiple of
+    8 and, above BT_I, of BT_I; N likewise of 128 and BT_J (callers
+    pad with the sentinels).  The address twin of
+    :func:`conflict_matrix_bits_pair`: same verdicts, K·N·A·B compares
+    instead of K·N·W words.
+    """
+    m, a = foot_addrs.shape
+    b, n = write_addrs_t.shape
+    bi, bj = _addr_tiles(m, n)
+    out = pl.pallas_call(
+        _addr_pair_kernel,
+        grid=(m // bi, n // bj),
+        in_specs=[
+            pl.BlockSpec((bi, a), lambda i, j: (i, 0)),
+            pl.BlockSpec((b, bj), lambda i, j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        interpret=interpret,
+    )(foot_addrs, write_addrs_t)
+    return out != 0
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def conflict_matrix_bits_addr_delta(foot_addrs: jax.Array,
+                                    write_addrs_t: jax.Array,
+                                    old: jax.Array, live: jax.Array,
+                                    *, interpret: bool) -> jax.Array:
+    """Incremental (K, K) conflict update over address lists: entry
+    (i, j) is recomputed iff ``live[i]`` or ``live[j]``, else ``old``.
+    Bit-identical to :func:`conflict_matrix_bits_delta` on the packed
+    sets of the same lists, stale entries included.
+
+    foot_addrs (K, 2L), write_addrs_t (L, K) as in
+    :func:`conflict_matrix_bits_addr_pair`; ``old`` (K, K) int32 is last
+    round's table, ``live`` (K,) int32.  K must be a multiple of
+    max(BT_I, BT_J), or below both and a multiple of 128.
+    """
+    k, a = foot_addrs.shape
+    b = write_addrs_t.shape[0]
+    bi, bj = _addr_tiles(k, k)
+    live = live.astype(jnp.int32)
+    out = pl.pallas_call(
+        _addr_delta_kernel,
+        grid=(k // bi, k // bj),
+        in_specs=[
+            pl.BlockSpec((bi, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bj), lambda i, j: (0, j)),
+            pl.BlockSpec((bi, a), lambda i, j: (i, 0)),
+            pl.BlockSpec((b, bj), lambda i, j: (0, j)),
+            pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((k, k), jnp.int32),
+        interpret=interpret,
+    )(live.reshape(k, 1), live.reshape(1, k), foot_addrs, write_addrs_t,
+      old)
+    return out

@@ -46,6 +46,37 @@ def _packed_intersects(foot_bits: jax.Array,
             ).any(axis=2)
 
 
+def _slots(addrs: jax.Array, n: jax.Array, void: int) -> jax.Array:
+    """(K, L) addresses with the slots at or past ``n`` set to ``void``."""
+    valid = jnp.arange(addrs.shape[1])[None, :] < n[:, None]
+    return jnp.where(valid, addrs, void)
+
+
+def _foot_addrs(raddrs, rn, waddrs, wn) -> jax.Array:
+    """(K, 2L) footprint lists, reads then writes, FOOT_VOID where
+    invalid — the row operand of the address kernels."""
+    return jnp.concatenate([_slots(raddrs, rn, _conf.FOOT_VOID),
+                            _slots(waddrs, wn, _conf.FOOT_VOID)], axis=1)
+
+
+def _write_addrs_t(waddrs, wn) -> jax.Array:
+    """(L, K) write lists, WRITE_VOID where invalid, transposed so that
+    write sets lie on lanes — the column operand of the address
+    kernels."""
+    return _slots(waddrs, wn, _conf.WRITE_VOID).T
+
+
+def _addr_intersects(foot: jax.Array, write_t: jax.Array) -> jax.Array:
+    """(M, N) bool, entry (i, j) = any(foot[i, a] == write_t[b, j]): the
+    rectangular address-compare Pallas kernel, operands padded with
+    their sentinels (TPU only)."""
+    m, n = foot.shape[0], write_t.shape[1]
+    f = _pad_to(foot, 8 if m <= _conf.BT_I else _conf.BT_I, 0,
+                _conf.FOOT_VOID)
+    w = _pad_to(write_t, _conf.BT_J, 1, _conf.WRITE_VOID)
+    return _conf.conflict_matrix_bits_addr_pair(f, w, interpret=False)[:m, :n]
+
+
 def validate(read_addrs: jax.Array, read_n: jax.Array,
              written_addrs: jax.Array, written_n: jax.Array,
              n_objects: int) -> jax.Array:
@@ -89,91 +120,44 @@ def conflict_matrix(raddrs: jax.Array, rn: jax.Array, waddrs: jax.Array,
     means footprint(i) = reads(i) ∪ writes(i) intersects writes(j).
 
     raddrs/waddrs (K, L) masked by rn/wn (K,).  On TPU this is the tiled
-    bitset-intersection Pallas kernel (conflict.py) over bit-packed
-    address sets; off-TPU it falls back to the dense-mask reference
-    formulation (same verdicts, asserted in tests/test_kernels.py).
+    address-compare Pallas kernel (conflict.py); off-TPU it falls back to
+    the dense-mask reference formulation (same verdicts, asserted in
+    tests/test_kernels.py).
     """
     if not _on_tpu():
         return _conflict_matrix_dense(raddrs, rn, waddrs, wn, n_objects)
-    return _packed_intersects(*packed_footprints(raddrs, rn, waddrs, wn,
-                                                 n_objects))
-
-
-def packed_footprints(raddrs: jax.Array, rn: jax.Array, waddrs: jax.Array,
-                      wn: jax.Array, n_objects: int
-                      ) -> tuple[jax.Array, jax.Array]:
-    """Bit-pack a batch's (footprint, write-set) address sets into
-    (K, ceil(O/32)) int32 words — the carried representation behind the
-    incremental conflict table (protocol.RoundState)."""
-    read_bits = _val.pack_addr_sets(raddrs, rn, n_objects)
-    write_bits = _val.pack_addr_sets(waddrs, wn, n_objects)
-    return read_bits | write_bits, write_bits
-
-
-def update_packed_footprints(foot_bits: jax.Array, write_bits: jax.Array,
-                             raddrs: jax.Array, rn: jax.Array,
-                             waddrs: jax.Array, wn: jax.Array,
-                             live: jax.Array, n_objects: int
-                             ) -> tuple[jax.Array, jax.Array]:
-    """Carry packed footprints across engine rounds: re-pack only the rows
-    of live (re-executed) transactions, keep settled rows' words.
-
-    Dead rows are packed with their counts masked to 0 (cheap — packing is
-    O(K·L) scatter work either way) and then dropped by the merge, so the
-    output rows for settled transactions are bit-identical to the carried
-    state from the round they last executed in.
-    """
-    fresh_foot, fresh_write = packed_footprints(
-        raddrs, jnp.where(live, rn, 0), waddrs, jnp.where(live, wn, 0),
-        n_objects)
-    keep = live[:, None]
-    return (jnp.where(keep, fresh_foot, foot_bits),
-            jnp.where(keep, fresh_write, write_bits))
-
-
-def update_packed_footprints_compact(foot_bits: jax.Array,
-                                     write_bits: jax.Array,
-                                     raddrs: jax.Array, rn: jax.Array,
-                                     waddrs: jax.Array, wn: jax.Array,
-                                     idx: jax.Array, valid: jax.Array,
-                                     n_objects: int
-                                     ) -> tuple[jax.Array, jax.Array]:
-    """Compact variant of :func:`update_packed_footprints`: the round's
-    re-executed rows arrive as a gathered (C, L) block
-    (``raddrs``/``rn``/``waddrs``/``wn`` from ``txn.run_compact``) plus
-    the row indices they came from; pack just those C rows — O(C·L)
-    instead of O(K·L) — and scatter them over the carried (K, W) words.
-    ``valid`` masks gather padding (possibly duplicate indices), which is
-    dropped rather than scattered."""
-    from repro.core.txn import scatter_rows
-    cfoot, cwrite = packed_footprints(
-        raddrs, jnp.where(valid, rn, 0), waddrs, jnp.where(valid, wn, 0),
-        n_objects)
-    return (scatter_rows(foot_bits, cfoot, idx, valid),
-            scatter_rows(write_bits, cwrite, idx, valid))
+    return _addr_intersects(_foot_addrs(raddrs, rn, waddrs, wn),
+                            _write_addrs_t(waddrs, wn))
 
 
 def conflict_matrix_delta_compact(foot_bits: jax.Array,
                                   write_bits: jax.Array, old: jax.Array,
                                   idx: jax.Array, valid: jax.Array,
                                   n_objects: int) -> jax.Array:
-    """Compacted variant of :func:`conflict_matrix_delta`: instead of a
-    masked pass over the full (K, K) grid, compute only the two strips the
-    round actually changed — rows idx (the C live footprints against every
-    write set, (C, K)) and columns idx (every footprint against the C live
-    write sets, (K, C)) — and scatter them over last round's table.
+    """Compacted variant of :func:`conflict_matrix_delta` (one shard's
+    words under the sharded layout): instead of a masked pass over the
+    full (K, K) grid, compute only the two strips the round actually
+    changed — rows idx (the C live footprints against every write set,
+    (C, K)) and columns idx (every footprint against the C live write
+    sets, (K, C)) — and scatter them over last round's table.
 
     On TPU both strips come from the rectangular bitset-intersection
     Pallas kernel (conflict.conflict_matrix_bits_pair): O(C·K·W) device
     work instead of O(K²·W).  Off-TPU a dense bit-ops fallback with
     identical verdicts (asserted in tests).  ``foot_bits``/``write_bits``
     must ALREADY hold the refreshed live rows
-    (:func:`update_packed_footprints_compact`).
+    (:func:`update_packed_footprints_compact_sharded`).
     """
-    k = foot_bits.shape[0]
     row_strip = _packed_intersects(foot_bits[idx], write_bits)
     col_strip = _packed_intersects(foot_bits, write_bits[idx])
+    return _scatter_strips(old, row_strip, col_strip, idx, valid)
+
+
+def _scatter_strips(old, row_strip, col_strip, idx, valid):
+    """Rows ``idx`` of ``old`` := row_strip (C, K), then columns ``idx``
+    := col_strip (K, C); gather padding (``~valid``) is dropped."""
     from repro.core.txn import scatter_rows
+    k = old.shape[0]
     new = scatter_rows(old, row_strip, idx, valid)
     # column twin of scatter_rows: same sentinel-drop contract, axis 1
     tgt = jnp.where(valid, idx, k)
@@ -183,9 +167,10 @@ def conflict_matrix_delta_compact(foot_bits: jax.Array,
 def conflict_matrix_delta(foot_bits: jax.Array, write_bits: jax.Array,
                           old: jax.Array, live: jax.Array,
                           n_objects: int) -> jax.Array:
-    """Incremental conflict-table update over carried packed footprints:
-    entry (i, j) is recomputed iff transaction i or j re-executed this
-    round (``live``), otherwise last round's verdict is carried.
+    """Incremental conflict-table update over carried packed footprints
+    (one shard's words under the sharded layout): entry (i, j) is
+    recomputed iff transaction i or j re-executed this round (``live``),
+    otherwise last round's verdict is carried.
 
     On TPU this is the masked-row variant of the bitset-intersection
     Pallas kernel (conflict.conflict_matrix_bits_delta — dead blocks skip
@@ -210,21 +195,76 @@ def conflict_matrix_delta(foot_bits: jax.Array, write_bits: jax.Array,
     return jnp.where(refresh, fresh, old)
 
 
+def conflict_matrix_delta_addrs(raddrs: jax.Array, rn: jax.Array,
+                                waddrs: jax.Array, wn: jax.Array,
+                                old: jax.Array, live: jax.Array,
+                                n_objects: int) -> jax.Array:
+    """The carried one-chip conflict table's round update: entry (i, j)
+    is recomputed iff transaction i or j re-executed this round
+    (``live``), otherwise last round's verdict is carried — from the
+    round's (K, L) lists (``res`` with the live rows already
+    re-executed), with no packed state.
+
+    On TPU the masked address-compare Pallas kernel
+    (conflict.conflict_matrix_bits_addr_delta); elsewhere the dense
+    recompute-and-select fallback, with identical verdicts.
+    """
+    k = raddrs.shape[0]
+    if not _on_tpu():
+        fresh = _conflict_matrix_dense(raddrs, rn, waddrs, wn, n_objects)
+        refresh = live[:, None].astype(bool) | live[None, :].astype(bool)
+        return jnp.where(refresh, fresh, old)
+    rows = max(_conf.BT_I, _conf.BT_J)
+    foot = _pad_to(_foot_addrs(raddrs, rn, waddrs, wn), rows, 0,
+                   _conf.FOOT_VOID)
+    write_t = _pad_to(_write_addrs_t(waddrs, wn), rows, 1, _conf.WRITE_VOID)
+    old_p = _pad_to(_pad_to(old.astype(jnp.int32), rows, 0), rows, 1)
+    live_p = _pad_to(live.astype(jnp.int32), rows, 0)
+    out = _conf.conflict_matrix_bits_addr_delta(foot, write_t, old_p, live_p,
+                                                interpret=False)
+    return out[:k, :k] != 0
+
+
+def conflict_matrix_delta_compact_addrs(raddrs: jax.Array, rn: jax.Array,
+                                        waddrs: jax.Array, wn: jax.Array,
+                                        old: jax.Array, idx: jax.Array,
+                                        valid: jax.Array,
+                                        n_objects: int) -> jax.Array:
+    """Compacted variant of :func:`conflict_matrix_delta_addrs`: the
+    (C, K) row strip and (K, C) column strip of rows ``idx`` (``valid``
+    masks gather padding), from the merged (K, L) lists (``res`` after
+    the compact block's scatter), by the rectangular address-compare
+    kernel — O(C·K·2L·L) on TPU.  Off TPU, the dense
+    recompute-and-select of the rows ``idx`` marks."""
+    if not _on_tpu():
+        k = raddrs.shape[0]
+        live = jnp.zeros((k,), bool).at[jnp.where(valid, idx, k)].set(
+            True, mode="drop")
+        return conflict_matrix_delta_addrs(raddrs, rn, waddrs, wn, old,
+                                           live, n_objects)
+    foot = _foot_addrs(raddrs, rn, waddrs, wn)
+    write_t = _write_addrs_t(waddrs, wn)
+    row_strip = _addr_intersects(foot[idx], write_t)
+    col_strip = _addr_intersects(foot, write_t[:, idx])
+    return _scatter_strips(old, row_strip, col_strip, idx, valid)
+
+
 # --------------------------------------------------------------------------
 # Shard-partitioned conflict analysis (PR 5)
 # --------------------------------------------------------------------------
 #
 # Under the sharded store layout (tstore.StoreLayout, S contiguous range
-# shards of C = ceil(O/S) objects) the packed footprints decompose per
-# shard: each shard packs only the addresses in its range into
-# (K, ceil(C/32)) words — the conflict kernels' W axis shrinks by S —
-# and the global conflict verdict is the OR over shards:
+# shards of C = ceil(O/S) objects) the conflict table is built from
+# footprints packed per shard: each shard packs only the addresses in
+# its range into (K, ceil(C/32)) words — the bitset kernels' W axis
+# shrinks by S — and the global conflict verdict is the OR over shards:
 #
 #     footprint(i) ∩ writes(j) ≠ ∅  ⟺  ∃s: foot_s(i) ∩ writes_s(j) ≠ ∅
 #
 # because the shards partition the address space.  Every function below
-# is the per-shard twin of a dense one above, OR-reducing S independent
-# intersections through :func:`_or_over_shards` — one bitset kernel per
+# OR-reduces S independent intersections (conflict_matrix_delta and
+# conflict_matrix_delta_compact above on one shard's words) through
+# :func:`_or_over_shards` — one bitset kernel per
 # shard, on the shard's own device under ``jax.shard_map`` when the
 # layout carries a mesh (a loop on one device otherwise), dense bit-ops
 # off-TPU; verdicts are bit-identical to the dense formulation (asserted
@@ -259,9 +299,11 @@ def update_packed_footprints_sharded(foot_bits: jax.Array,
                                      waddrs: jax.Array, wn: jax.Array,
                                      live: jax.Array, layout
                                      ) -> tuple[jax.Array, jax.Array]:
-    """Sharded twin of :func:`update_packed_footprints`: re-pack only the
-    live rows (every shard's row strip for a live transaction), keep the
-    settled rows' words in all S shards."""
+    """Carry the per-shard packed footprints across engine rounds:
+    re-pack only the live (re-executed) rows — every shard's row strip
+    for a live transaction — and keep the settled rows' words in all S
+    shards.  Dead rows are packed with their counts masked to 0 and then
+    dropped by the merge."""
     fresh_foot, fresh_write = packed_footprints_sharded(
         raddrs, jnp.where(live, rn, 0), waddrs, jnp.where(live, wn, 0),
         layout)
@@ -280,9 +322,11 @@ def update_packed_footprints_compact_sharded(foot_bits: jax.Array,
                                              valid: jax.Array, layout
                                              ) -> tuple[jax.Array,
                                                         jax.Array]:
-    """Sharded twin of :func:`update_packed_footprints_compact`: pack the
-    gathered (C_rows, L) block per shard — O(S·C_rows·L) — and scatter
-    each shard's row strip over the carried (S, K, W_s) words."""
+    """Compact variant of :func:`update_packed_footprints_sharded`: pack
+    the gathered (C_rows, L) block (``txn.run_compact``'s rows ``idx``)
+    per shard — O(S·C_rows·L) — and scatter each shard's row strip over
+    the carried (S, K, W_s) words; ``valid`` masks gather padding, which
+    is dropped rather than scattered."""
     from repro.core.txn import scatter_rows
     cfoot, cwrite = packed_footprints_sharded(
         raddrs, jnp.where(valid, rn, 0), waddrs, jnp.where(valid, wn, 0),
@@ -379,10 +423,10 @@ def conflict_matrix_delta_compact_sharded(foot_bits: jax.Array,
 # row-local), so clean reads replay bit-identically and the write set
 # need not be checked.  The dirty set packs into ONE bitset row
 # (word = a // 32, bit = a % 32 — validate.py's convention), turning
-# the whole validation into a (K, 1) rectangular strip of the same
-# bitset-intersection Pallas kernel the compact round update uses
-# (conflict.conflict_matrix_bits_pair); off-TPU a dense gather
-# fallback with identical verdicts (asserted in tests/test_pipeline.py).
+# the whole validation into a (K, 1) rectangular strip of the
+# bitset-intersection Pallas kernel (conflict.conflict_matrix_bits_pair);
+# off-TPU a dense gather fallback with identical verdicts (asserted in
+# tests/test_pipeline.py).
 
 
 def spec_dirty_words(versions: jax.Array, snap_gv: jax.Array,
@@ -470,9 +514,16 @@ def cross_conflicts(reader_raddrs: jax.Array, reader_rn: jax.Array,
     wave-speculative retry validation (PR 10): the reader and writer
     verdicts come from DIFFERENT result blocks (speculative footprints
     vs a wave's re-executed write sets), so neither the carried table
-    nor the delta strips apply.  On TPU both sides bit-pack and the
-    strip is one ``conflict.conflict_matrix_bits_pair`` launch; off-TPU
-    a dense bit-ops fallback with identical verdicts."""
+    nor the delta strips apply.  On TPU the strip is one launch of the
+    address-compare kernel (``conflict.conflict_matrix_bits_addr_pair``);
+    off-TPU a dense bit-ops fallback with identical verdicts."""
+    if _on_tpu():
+        foot = (_slots(reader_raddrs, reader_rn, _conf.FOOT_VOID)
+                if reads_only else
+                _foot_addrs(reader_raddrs, reader_rn, reader_waddrs,
+                            reader_wn))
+        return _addr_intersects(foot,
+                                _write_addrs_t(writer_waddrs, writer_wn))
     rbits = _val.pack_addr_sets(reader_raddrs, reader_rn, n_objects)
     if not reads_only:
         rbits = rbits | _val.pack_addr_sets(reader_waddrs, reader_wn,

@@ -72,6 +72,13 @@ def test_validate_property(k, l, n_objects):
 
 
 # --------------------------------------------------------- conflict matrix
+def _packed(ra, rn, wa, wn, n_objects):
+    """(footprint, write-set) bitsets, (K, ceil(O/32)) words each: the
+    operands of the bitset kernels and of their ops wrappers."""
+    write = pack_addr_sets(wa, wn, n_objects)
+    return pack_addr_sets(ra, rn, n_objects) | write, write
+
+
 def test_conflict_matrix_kernel_vs_bits_ref():
     rng = np.random.default_rng(5)
     k = max(conflict_mod.BI, conflict_mod.BJ)
@@ -201,7 +208,7 @@ def test_conflict_matrix_delta_compact_vs_masked_delta():
                   jnp.asarray(rng.integers(0, l + 1, (k,)), jnp.int32))
     ra, rn = mk()
     wa, wn = mk()
-    foot, write = ops.packed_footprints(ra, rn, wa, wn, n_objects)
+    foot, write = _packed(ra, rn, wa, wn, n_objects)
     old = jnp.asarray(rng.random((k, k)) < 0.2)
     for n_live in (0, 1, 7, k):
         live = np.zeros(k, bool)
@@ -216,51 +223,60 @@ def test_conflict_matrix_delta_compact_vs_masked_delta():
 
 
 def test_update_packed_footprints_compact_matches_masked():
+    """The sharded store's packed carry: the compact update (gathered
+    rows scattered back) equals the masked one."""
+    from repro.core.tstore import StoreLayout
     from repro.core.txn import gather_live_indices
     rng = np.random.default_rng(44)
     k, l, n_objects = 14, 5, 90
+    layout = StoreLayout(n_objects, 3)
     mk = lambda: (jnp.asarray(rng.integers(0, n_objects, (k, l)), jnp.int32),
                   jnp.asarray(rng.integers(0, l + 1, (k,)), jnp.int32))
     ra0, rn0 = mk()
     wa0, wn0 = mk()
-    foot0, write0 = ops.packed_footprints(ra0, rn0, wa0, wn0, n_objects)
+    foot0, write0 = ops.packed_footprints_sharded(ra0, rn0, wa0, wn0, layout)
     ra1, rn1 = mk()
     wa1, wn1 = mk()
     live = jnp.asarray(rng.random(k) < 0.4)
     width = max(1, int(live.sum()))
     idx, valid = gather_live_indices(live, width)
-    ref = ops.update_packed_footprints(foot0, write0, ra1, rn1, wa1, wn1,
-                                       live, n_objects)
-    got = ops.update_packed_footprints_compact(
+    ref = ops.update_packed_footprints_sharded(foot0, write0, ra1, rn1, wa1,
+                                               wn1, live, layout)
+    got = ops.update_packed_footprints_compact_sharded(
         foot0, write0, ra1[idx], rn1[idx], wa1[idx], wn1[idx], idx, valid,
-        n_objects)
+        layout)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
 
 
 def test_update_packed_footprints_refreshes_live_rows_only():
+    """The sharded store's packed carry re-packs the live rows in every
+    shard and keeps the settled rows' words."""
+    from repro.core.tstore import StoreLayout
     rng = np.random.default_rng(8)
     k, l, n_objects = 12, 5, 100
+    layout = StoreLayout(n_objects, 3)
     mk = lambda: (jnp.asarray(rng.integers(0, n_objects, (k, l)), jnp.int32),
                   jnp.asarray(rng.integers(0, l + 1, (k,)), jnp.int32))
     ra0, rn0 = mk()
     wa0, wn0 = mk()
-    foot0, write0 = ops.packed_footprints(ra0, rn0, wa0, wn0, n_objects)
+    foot0, write0 = ops.packed_footprints_sharded(ra0, rn0, wa0, wn0, layout)
     ra1, rn1 = mk()
     wa1, wn1 = mk()
     live = jnp.asarray(rng.random(k) < 0.4)
-    foot, write = ops.update_packed_footprints(
-        foot0, write0, ra1, rn1, wa1, wn1, live, n_objects)
-    exp_foot, exp_write = ops.packed_footprints(ra1, rn1, wa1, wn1, n_objects)
+    foot, write = ops.update_packed_footprints_sharded(
+        foot0, write0, ra1, rn1, wa1, wn1, live, layout)
+    exp_foot, exp_write = ops.packed_footprints_sharded(ra1, rn1, wa1, wn1,
+                                                        layout)
     lv = np.asarray(live)
-    np.testing.assert_array_equal(np.asarray(foot)[lv],
-                                  np.asarray(exp_foot)[lv])
-    np.testing.assert_array_equal(np.asarray(write)[lv],
-                                  np.asarray(exp_write)[lv])
-    np.testing.assert_array_equal(np.asarray(foot)[~lv],
-                                  np.asarray(foot0)[~lv])
-    np.testing.assert_array_equal(np.asarray(write)[~lv],
-                                  np.asarray(write0)[~lv])
+    np.testing.assert_array_equal(np.asarray(foot)[:, lv],
+                                  np.asarray(exp_foot)[:, lv])
+    np.testing.assert_array_equal(np.asarray(write)[:, lv],
+                                  np.asarray(exp_write)[:, lv])
+    np.testing.assert_array_equal(np.asarray(foot)[:, ~lv],
+                                  np.asarray(foot0)[:, ~lv])
+    np.testing.assert_array_equal(np.asarray(write)[:, ~lv],
+                                  np.asarray(write0)[:, ~lv])
 
 
 def test_conflict_matrix_delta_op_dense_fallback():
@@ -272,7 +288,7 @@ def test_conflict_matrix_delta_op_dense_fallback():
                   jnp.asarray(rng.integers(0, l + 1, (k,)), jnp.int32))
     ra, rn = mk()
     wa, wn = mk()
-    foot, write = ops.packed_footprints(ra, rn, wa, wn, n_objects)
+    foot, write = _packed(ra, rn, wa, wn, n_objects)
     old = jnp.asarray(rng.random((k, k)) < 0.2)
     live = jnp.asarray(rng.random(k) < 0.5)
     got = np.asarray(ops.conflict_matrix_delta(foot, write, old, live,
@@ -281,6 +297,116 @@ def test_conflict_matrix_delta_op_dense_fallback():
     lv = np.asarray(live)
     exp = np.where(lv[:, None] | lv[None, :], full, np.asarray(old))
     np.testing.assert_array_equal(got, exp)
+
+
+# ------------------------------------------- conflict matrix: address lists
+def _addr_case(k, l, n_objects, seed):
+    """(K, L) read/write lists over ``n_objects`` with the awkward cases
+    planted: empty rows (rn = wn = 0), a row with repeated addresses,
+    and the addresses 0 and O - 1 (as a write, then as a read)."""
+    rng = np.random.default_rng(seed)
+    ra = rng.integers(0, n_objects, (k, l)).astype(np.int32)
+    wa = rng.integers(0, n_objects, (k, l)).astype(np.int32)
+    rn = rng.integers(0, l + 1, (k,)).astype(np.int32)
+    wn = rng.integers(0, l + 1, (k,)).astype(np.int32)
+    rn[:3] = 0
+    wn[1:4] = 0
+    ra[4], rn[4] = ra[4, 0], l                  # one address, repeated
+    wa[5], wn[5] = wa[5, 0], l
+    wa[6, 0], wn[6] = 0, max(wn[6], 1)
+    ra[7, 0], rn[7] = 0, max(rn[7], 1)
+    wa[8, 0], wn[8] = n_objects - 1, max(wn[8], 1)
+    ra[9, l - 1], rn[9] = n_objects - 1, l
+    return tuple(jnp.asarray(x) for x in (ra, rn, wa, wn))
+
+
+def _addr_operands(ra, rn, wa, wn, rows=None, cols=None):
+    """The address kernels' operands, padded with their sentinels to
+    ``rows`` footprint rows and ``cols`` write-set columns."""
+    foot = ops._foot_addrs(ra, rn, wa, wn)
+    write_t = ops._write_addrs_t(wa, wn)
+    if rows is not None:
+        foot = jnp.pad(foot, ((0, rows - foot.shape[0]), (0, 0)),
+                       constant_values=conflict_mod.FOOT_VOID)
+    if cols is not None:
+        write_t = jnp.pad(write_t, ((0, 0), (0, cols - write_t.shape[1])),
+                          constant_values=conflict_mod.WRITE_VOID)
+    return foot, write_t
+
+
+def _bit_operands(ra, rn, wa, wn, n_objects):
+    foot, write = _packed(ra, rn, wa, wn, n_objects)
+    pad_w = (-foot.shape[1]) % conflict_mod.BW
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, pad_w)))
+    return pad(foot), pad(write)
+
+
+@pytest.mark.parametrize("k,l", [(256, 16), (1024, 4), (128, 8)])
+@pytest.mark.parametrize("live_frac", ["none", "one_row", 0.1, 0.5, 1.0])
+def test_conflict_addr_delta_kernel_equals_bitset_delta(k, l, live_frac):
+    """The address-compare delta kernel gives the bitset delta kernel's
+    table bit for bit (stale entries carried from the same ``old``), and
+    both give the dense reference on every refreshed entry."""
+    n_objects = 4000
+    ra, rn, wa, wn = _addr_case(k, l, n_objects, seed=k + l)
+    rng = np.random.default_rng(k * l)
+    old = jnp.asarray(rng.random((k, k)) < 0.3, jnp.int32)
+    if live_frac == "none":
+        live = np.zeros(k, np.int32)
+    elif live_frac == "one_row":
+        live = np.zeros(k, np.int32)
+        live[k // 3] = 1
+    else:
+        live = (rng.random(k) < live_frac).astype(np.int32)
+    live = jnp.asarray(live)
+    foot, write_t = _addr_operands(ra, rn, wa, wn)
+    got = np.asarray(conflict_mod.conflict_matrix_bits_addr_delta(
+        foot, write_t, old, live, interpret=True))
+    fb, wb = _bit_operands(ra, rn, wa, wn, n_objects)
+    exp = np.asarray(conflict_mod.conflict_matrix_bits_delta(
+        fb, wb, old, live, interpret=True))
+    np.testing.assert_array_equal(got, exp)
+    dense = np.asarray(ops._conflict_matrix_dense(ra, rn, wa, wn, n_objects))
+    lv = np.asarray(live).astype(bool)
+    np.testing.assert_array_equal(
+        got != 0, np.where(lv[:, None] | lv[None, :], dense,
+                           np.asarray(old) != 0))
+
+
+@pytest.mark.parametrize("k,l,c", [(256, 16, 64), (1024, 4, 16),
+                                   (128, 8, 8)])
+@pytest.mark.parametrize("strip", ["rows", "cols"])
+def test_conflict_addr_pair_kernel_equals_bitset_pair(k, l, c, strip):
+    """The rectangular address kernel on the compact rungs' strips —
+    (C, K) live footprints against every write set, (K, C) every
+    footprint against the live write sets — equals the bitset pair
+    kernel and the dense reference."""
+    n_objects = 4000
+    ra, rn, wa, wn = _addr_case(k, l, n_objects, seed=3 * k + l)
+    idx = jnp.asarray(np.random.default_rng(c).choice(k, c, replace=False),
+                      jnp.int32)
+    foot, write_t = _addr_operands(ra, rn, wa, wn)
+    fb, wb = _bit_operands(ra, rn, wa, wn, n_objects)
+    dense = np.asarray(ops._conflict_matrix_dense(ra, rn, wa, wn, n_objects))
+    if strip == "rows":
+        got = conflict_mod.conflict_matrix_bits_addr_pair(
+            foot[idx], write_t, interpret=True)
+        exp = conflict_mod.conflict_matrix_bits_pair(
+            jnp.pad(fb[idx], ((0, (-c) % conflict_mod.BI), (0, 0))), wb,
+            interpret=True)[:c]
+        ref_strip = dense[np.asarray(idx)]
+    else:
+        cols = -(-c // 128) * 128
+        got = conflict_mod.conflict_matrix_bits_addr_pair(
+            foot, jnp.pad(write_t[:, idx], ((0, 0), (0, cols - c)),
+                          constant_values=conflict_mod.WRITE_VOID),
+            interpret=True)[:, :c]
+        exp = conflict_mod.conflict_matrix_bits_pair(
+            fb, jnp.pad(wb[idx], ((0, cols - c), (0, 0))),
+            interpret=True)[:, :c]
+        ref_strip = dense[:, np.asarray(idx)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+    np.testing.assert_array_equal(np.asarray(got), ref_strip)
 
 
 # ------------------------------------------------------------- fused adamw

@@ -91,6 +91,23 @@ def test_delta_kernel_compiles(one_chip):
     assert KERNEL in compiled.as_text()
 
 
+@pytest.mark.parametrize("k,length", [(256, 16), (1024, 4)])
+def test_addr_kernels_compile(one_chip, k, length):
+    """The address kernels at the served cells' shapes: YCSB's 256 txns
+    of 16 slots, p2p's 1,024 of 4; the pair kernel on a 64-row strip."""
+    i32 = jnp.int32
+    delta = jax.jit(C.conflict_matrix_bits_addr_delta,
+                    static_argnames="interpret").lower(
+        _sds((k, 2 * length), i32, one_chip),
+        _sds((length, k), i32, one_chip), _sds((k, k), i32, one_chip),
+        _sds((k,), i32, one_chip), interpret=False).compile()
+    pair = jax.jit(C.conflict_matrix_bits_addr_pair,
+                   static_argnames="interpret").lower(
+        _sds((64, 2 * length), i32, one_chip),
+        _sds((length, k), i32, one_chip), interpret=False).compile()
+    assert KERNEL in delta.as_text() and KERNEL in pair.as_text()
+
+
 def test_spec_strip_compiles(one_chip, tpu_branch):
     """The (K x 128) strip of the cross-batch validation: K read sets
     against the one-row dirty bitmap padded to a 128-row block."""
@@ -107,7 +124,13 @@ def test_pcc_step_compiles_one_chip(one_chip, tpu_branch):
                    gv=_sds((), jnp.int32, one_chip))
     compiled = jax.jit(get_engine("pcc").raw, static_argnums=4).lower(
         *_step_args(store, one_chip)).compile()
-    assert KERNEL in compiled.as_text()
+    text = compiled.as_text()
+    # short transactions over 2^20 records: the conflict table comes from
+    # the address kernels, and no (K, W) bitset is built or carried
+    kernels = [line.split()[0] for line in text.splitlines()
+               if KERNEL in line]
+    assert any(k.startswith("%conflict_matrix_bits_addr") for k in kernels)
+    assert f"s32[{K},{W}]" not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
 
